@@ -11,7 +11,9 @@ bandwidth per kernel against the peak-HBM line::
   PYTHONPATH=src python -m benchmarks.roofline_report --measure --ops 4096
 
 With a positional path, ``--measure`` instead reads ``dispatch_stats``
-from that JSON report (any file carrying a ``dispatch_stats`` block).
+from that JSON report (any file carrying a ``dispatch_stats`` block and a
+top-level ``device_kind``).  Peaks come from ``repro.roofline.hardware``
+by device kind; a kind missing from that table is an error.
 """
 from __future__ import annotations
 
@@ -91,16 +93,23 @@ def _find_dispatch_stats(obj):
 
 def measure(path=None, *, ops=4096, batch=256, trace_out=None):
     """Measured per-kernel table: live device run, or a saved report."""
+    import jax
+
     from repro.obs.trace import Tracer
-    from repro.roofline.analysis import measured_kernel_table
     from repro.roofline import hardware as hw
+    from repro.roofline.analysis import measured_kernel_table
 
     if path is not None:
-        stats = _find_dispatch_stats(json.load(open(path)))
-        if not stats:
-            raise SystemExit(f"{path}: no dispatch_stats block found "
-                             "(run with a tracer attached)")
+        with open(path) as f:
+            report = json.load(f)
+        stats = _find_dispatch_stats(report)
+        kind = report.get("device_kind")
+        if not stats or kind is None:
+            raise SystemExit(f"{path}: needs a dispatch_stats block (run "
+                             "with a tracer attached) and a top-level "
+                             "device_kind it was measured on")
     else:
+        kind = jax.devices()[0].device_kind
         import numpy as np
         from repro.core.engine_api import make_engine
 
@@ -119,8 +128,9 @@ def measure(path=None, *, ops=4096, batch=256, trace_out=None):
             tracer.save(trace_out)
             print(f"wrote {trace_out}")
 
-    rows = measured_kernel_table(stats)
-    print(f"Measured kernel bandwidth (peak HBM {hw.HBM_BW/1e9:.0f} GB/s):")
+    rows = measured_kernel_table(stats, device_kind=kind)
+    print(f"Measured kernel bandwidth on {kind} "
+          f"(peak HBM {hw.peaks(kind).hbm_bw/1e9:.0f} GB/s):")
     print("| kernel | dispatches | wall s | MiB moved | achieved GB/s "
           "| % of peak |")
     print("|---|---|---|---|---|---|")

@@ -10,6 +10,8 @@ import json
 import os
 import time
 
+from repro.compile_cache import place_compile_cache
+
 from . import (bench_engine, bench_ingest_device, bench_kernels, fig4_fanout,
                fig5_dtree_size, fig67_insertion, fig89_query, fig_failover,
                fig_mixed, fig_range, fig_recovery, fig_saturation,
@@ -40,6 +42,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smaller workloads (CI mode)")
     args = ap.parse_args()
+    place_compile_cache()
 
     all_rows = {}
     verdicts = []

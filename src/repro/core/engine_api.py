@@ -702,25 +702,19 @@ class DeviceNBTreeEngine(StorageEngine):
     def dump_live(self) -> tuple:
         run_keys = np.asarray(self.idx.run_keys)
         run_vals = np.asarray(self.idx.run_vals)
-        seen: dict = {}
-
         # pre-order (ancestors first) + leftmost-first within a run is the
-        # freshest-copy-wins order both query paths resolve by.
-        def rec(node):
-            ks = run_keys[node.nid][: node.count]
-            vs = run_vals[node.nid][: node.count]
-            for k, v in zip(ks.tolist(), vs.tolist()):
-                if k not in seen:
-                    seen[k] = v
-            for c in node.children:
-                rec(c)
-
-        rec(self.idx.root)
-        live = sorted((k, v) for k, v in seen.items()
-                      if v != self._tombstone32)
-        keys = np.asarray([k for k, _ in live], KEY_DTYPE)
-        vals = np.asarray([v for _, v in live], VAL_DTYPE)
-        return keys, vals
+        # freshest-copy-wins order both query paths resolve by, so the
+        # first occurrence of each key in that order is its live copy.
+        ks, vs, stack = [], [], [self.idx.root]
+        while stack:
+            node = stack.pop()
+            ks.append(run_keys[node.nid, : node.count])
+            vs.append(run_vals[node.nid, : node.count])
+            stack.extend(reversed(node.children))
+        keys, first = np.unique(np.concatenate(ks), return_index=True)
+        vals = np.concatenate(vs)[first]
+        live = vals != self._tombstone32
+        return keys[live].astype(KEY_DTYPE), vals[live].astype(VAL_DTYPE)
 
     def count_live(self) -> int:
         return len(self.dump_live()[0])

@@ -37,23 +37,11 @@ ACT_RULES = {
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool | None = None):
-    """``jax.shard_map`` on current jax; ``jax.experimental.shard_map`` with
-    the equivalent ``auto``/``check_rep`` spelling on 0.4.x.
-
-    ``axis_names`` is the set of *manual* axes (None = all of them), as in
-    the new API; on 0.4.x it is translated to the complement ``auto`` set.
-    ``check_vma=None`` keeps each API's own default.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=axis_names, **kw)
-    from jax.experimental.shard_map import shard_map as sm_old
-    auto = frozenset(mesh.axis_names) - set(axis_names or mesh.axis_names)
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  auto=auto, **kw)
+    """``jax.shard_map``; ``axis_names`` is the set of *manual* axes (None =
+    all of them), and ``check_vma=None`` keeps JAX's default."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=axis_names, **kw)
 
 
 def mesh_axis_size(name: str) -> int:
@@ -72,9 +60,7 @@ def constrain(x, *logical):
     # activation constraints are dropped entirely: mixing them with manual
     # axes trips an XLA SPMD-partitioner CHECK (spmd_partitioner_util.cc:504,
     # jaxlib 0.8.2); GSPMD still propagates sharding from the in/out specs.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None and any(
-            t == axis_type.Manual for t in getattr(mesh, "axis_types", ())):
+    if any(t == jax.sharding.AxisType.Manual for t in mesh.axis_types):
         return x
     manual = set()
     spec = []

@@ -1,97 +1,211 @@
 """Pallas TPU kernel: streaming merge of two sorted runs (the flush hot loop).
 
 TPU adaptation of the paper's merge-sort flush (Sec. 4.1).  A sequential
-two-pointer merge is hostile to a vector machine, so we use the *merge-path*
-formulation, reorganized to be **gather-only** (TPU VMEM has fast dynamic
-gathers, no fast scatters): every output element k independently binary-
-searches the diagonal partition i(k) = |{a-elements among the first k merged
-elements}| over the two runs held entirely in VMEM, then gathers its key /
-value from ``a[i]`` or ``b[k-i]``.  log2(N) vectorized steps, no data-
-dependent control flow, MXU-free (pure VPU), fully pipelined across output
-tiles by the Pallas grid.
+two-pointer merge is hostile to a vector machine, so the merge is split the
+*merge-path* way into independent output tiles of ``TILE`` = 8x128
+elements, and each tile is merged with a fixed compare-exchange network
+that moves data only inside one ``(8, 128)`` vreg tile (Mosaic lowers no
+gather that crosses tiles):
 
-Tie-break: equal keys take the ``a`` element first — ``a`` is the newer
-stream, so leftmost-match queries see the freshest record (delta-record
-resolution, paper Sec. 3.2.2).
+1. **Diagonal splits** (XLA, outside the kernel): for every output tile
+   ``t``, ``i0 = i(t*TILE)`` is the number of ``a`` elements among the
+   first ``t*TILE`` merged ones, found by two sorted searches over the
+   ``a`` elements' merged ranks.  The splits are scalar-prefetched into
+   SMEM.
+2. **Windows** (BlockSpecs): the tile's output is the first ``TILE``
+   elements of ``merge(a[i0:], b[j0:])`` with ``j0 = t*TILE - i0``; the
+   index maps DMA just the two aligned ``(8, 128)`` blocks of each run that
+   cover ``[i0, i0 + TILE)`` and ``[j0, j0 + TILE)``.
+3. **In-tile merge** (kernel body): dynamic rolls align each window to
+   its start (``b`` is reversed once in XLA, so its window comes out
+   reversed), one half-cleaner keeps the smaller ``TILE`` of the bitonic
+   ``a ++ reverse(b)`` sequence, and ten more half-cleaners, each a pair
+   of rolls, sort it.
 
-Two entry points share the kernel body: ``merge_sorted`` (one pair of runs,
-1-d output-tile grid) and ``merge_sorted_batch`` (R independent pairs on a
-2-d ``(run, out-tile)`` grid — the one-dispatch fan-out the fused NB-tree
-emptying cascade uses to merge all children of a node at once).
+A bitonic network is not stable, so every element carries a tag that makes
+the order total: its window position for ``a`` (``0..TILE-1``), ``TILE`` +
+its position for ``b``, and ``EXCLUDED`` for the guard padding past the run
+ends.  Comparing ``(key, tag)`` reproduces the reference order exactly,
+including the tie-break: equal keys take the ``a`` element first — ``a`` is
+the newer stream, so leftmost-match queries see the freshest record
+(delta-record resolution, paper Sec. 3.2.2).
 
-VMEM budget: both runs (keys+values, uint32/int32) fully resident:
-4 arrays x 64 Ki x 4 B = 1 MiB at sigma = 64 Ki pairs — comfortably inside
-the ~128 MiB/core VMEM of v5e, leaving room for double-buffered output tiles.
+Two entry points share one ``(run, out-tile)`` grid: ``merge_sorted`` (one
+pair of runs) and ``merge_sorted_batch`` (R independent pairs — the
+one-dispatch fan-out the fused NB-tree emptying cascade uses to merge all
+children of a node at once).
+
+VMEM: each grid step holds eight ``(8, 128)`` 32-bit input blocks and two
+output blocks, double-buffered by the pipeline — 80 KiB whatever the run
+length, far under v5e's 16 MiB default scoped VMEM limit.  Run length is
+bounded by HBM: the kernel compiles for v5e with two runs of 2^22 keys.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import KEY_MAX32
 
 LANES = 128
 SUBLANES = 8
 TILE = SUBLANES * LANES  # output elements per grid step
+_LOG_LANES = LANES.bit_length() - 1
+#: tag of guard-padding elements: sorts after every real (key, tag) pair.
+EXCLUDED = 2 * TILE
 
 
-def _take(arr, idx):
-    """Clamped dynamic gather (Mosaic lowers to tpu.DynamicGather)."""
-    return jnp.take(arr, idx, mode="clip")
+def _flat_iota(shape):
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return row, col
 
 
-def _merge_kernel(a_keys_ref, a_vals_ref, b_keys_ref, b_vals_ref,
-                  ok_ref, ov_ref, *, n: int, m: int, steps: int,
-                  batched: bool = False):
-    a = a_keys_ref[...].reshape(-1)
-    b = b_keys_ref[...].reshape(-1)
-    av = a_vals_ref[...].reshape(-1)
-    bv = b_vals_ref[...].reshape(-1)
+def _window(blk0_ref, blk1_ref, off):
+    """The TILE elements starting ``off`` (< TILE) into two adjacent blocks.
 
-    # batched entry runs a (run, out-tile) grid; the run axis is resolved by
-    # the BlockSpecs, so the kernel body only needs its output-tile index.
-    tile = pl.program_id(1 if batched else 0)
-    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
-    k = tile * TILE + row * LANES + col  # global output index, (8, 128)
-
-    # --- merge-path binary search for i(k) --------------------------------
-    lo = jnp.maximum(0, k - m)
-    hi = jnp.minimum(k, n)
-    for _ in range(steps):
-        i = (lo + hi) >> 1
-        j = k - i
-        a_i = _take(a, jnp.clip(i, 0, n - 1))
-        b_jm1 = _take(b, jnp.clip(j - 1, 0, m - 1))
-        go_right = (lo < hi) & (a_i <= b_jm1)
-        lo = jnp.where(go_right, i + 1, lo)
-        hi = jnp.where(go_right, hi, i)
-
-    i = lo
-    j = k - i
-    a_i = _take(a, jnp.clip(i, 0, n - 1))
-    b_j = _take(b, jnp.clip(j, 0, m - 1))
-    take_a = (j >= m) | ((i < n) & (a_i <= b_j))
-    ok_ref[...] = jnp.where(take_a, a_i, b_j).reshape(ok_ref.shape)
-    ov_ref[...] = jnp.where(
-        take_a,
-        _take(av, jnp.clip(i, 0, n - 1)),
-        _take(bv, jnp.clip(j, 0, m - 1)),
-    ).reshape(ov_ref.shape)
+    A row roll then a lane roll, both by dynamic amounts; the lane roll's
+    wrap-around is taken from the next row.
+    """
+    x = jnp.concatenate([blk0_ref[...], blk1_ref[...]], axis=0)
+    rows = x.shape[0]
+    q, r = off >> _LOG_LANES, off & (LANES - 1)
+    # jnp.roll semantics: roll(x, k)[p] = x[p - k]
+    x = pltpu.roll(x, (rows - q) % rows, 0)          # x[row + q]
+    lane = pltpu.roll(x, (LANES - r) % LANES, 1)     # x[row, (col + r) % 128]
+    carry = pltpu.roll(lane, rows - 1, 0)            # lane[row + 1]
+    _, col = _flat_iota(x.shape)
+    return jnp.where(col < LANES - r, lane, carry)[:SUBLANES]
 
 
-def _pad_run(keys, vals, pad_to):
-    n = keys.shape[0]
-    if n == pad_to:
-        return keys, vals
-    return (
-        jnp.pad(keys, (0, pad_to - n), constant_values=KEY_MAX32),
-        jnp.pad(vals, (0, pad_to - n), constant_values=0),
-    )
+def _half_cleaners(k, v, g, pos, *, axis: int):
+    """The half-cleaner stages of a bitonic merge whose partners lie along
+    ``axis`` of an (8, 128) tile, largest stride first.
+
+    Stage b pairs flat position p with p ^ s, the ``(key, tag)`` smaller of
+    the two staying at the lower position; its partner is a roll by the
+    stride, dynamic so that the stages are one loop body.
+    """
+    size = k.shape[axis]
+    unit = LANES if axis == 0 else 1            # flat stride of one step
+
+    def stage(b, kvg):
+        k, v, g = kvg
+        sh = size >> (b + 1)
+        upper = (pos & (sh * unit)) != 0
+        # jnp.roll semantics: roll(x, k)[p] = x[p - k]
+        partner = lambda x: jnp.where(upper, pltpu.roll(x, sh, axis),
+                                      pltpu.roll(x, size - sh, axis))
+        pk, pv, pg = partner(k), partner(v), partner(g)
+        keep = _less(k, g, pk, pg) != upper
+        return (jnp.where(keep, k, pk), jnp.where(keep, v, pv),
+                jnp.where(keep, g, pg))
+
+    return jax.lax.fori_loop(0, size.bit_length() - 1, stage, (k, v, g))
+
+
+def _less(ka, ta, kb, tb):
+    return (ka < kb) | ((ka == kb) & (ta < tb))
+
+
+def _merge_kernel(split_ref, a0k, a1k, a0v, a1v, b0k, b1k, b0v, b1v,
+                  ok_ref, ov_ref, *, n: int, m: int, tiles: int):
+    t = pl.program_id(1)
+    i0 = split_ref[pl.program_id(0) * tiles + t]
+    j0 = t * TILE - i0
+    row, col = _flat_iota((SUBLANES, LANES))
+    pos = row * LANES + col
+
+    ak = _window(a0k, a1k, i0 & (TILE - 1))
+    av = _window(a0v, a1v, i0 & (TILE - 1))
+    at = jnp.where(i0 + pos < n, pos, EXCLUDED)
+    # b arrives reversed, so its window b[j0 + TILE - 1 - p] starts at
+    # m + TILE - j0 and a ++ reverse(b) is bitonic.
+    bk = _window(b0k, b1k, (m + TILE - j0) & (TILE - 1))
+    bv = _window(b0v, b1v, (m + TILE - j0) & (TILE - 1))
+    bt = jnp.where(j0 + (TILE - 1 - pos) < m, 2 * TILE - 1 - pos, EXCLUDED)
+
+    # half-cleaner over the 2*TILE bitonic sequence: keep the smaller half.
+    take_a = _less(ak, at, bk, bt)
+    k = jnp.where(take_a, ak, bk)
+    v = jnp.where(take_a, av, bv)
+    g = jnp.where(take_a, at, bt)
+    # bitonic merge of the kept half: strides TILE/2 .. LANES across
+    # sublanes, then LANES/2 .. 1 across lanes.
+    kvg = _half_cleaners(k, v, g, pos, axis=0)
+    k, v, _ = _half_cleaners(*kvg, pos, axis=1)
+    ok_ref[...] = k
+    ov_ref[...] = v
+
+
+def _diagonal_splits(a_keys, b_keys, n: int, m: int):
+    """Merge-path split ``i(t*TILE)`` of every output tile (a-first ties).
+
+    ``a[i]`` lands at merged position ``i + |{b < a[i]}|``, an increasing
+    sequence, so the number of ``a`` elements among the first ``k`` merged
+    ones is a sorted search of ``k`` in it.
+    """
+    pos_a = jnp.arange(n, dtype=jnp.int32) + jnp.searchsorted(
+        b_keys[:m], a_keys[:n], side="left").astype(jnp.int32)
+    k = jnp.arange((n + m) // TILE, dtype=jnp.int32) * TILE
+    return jnp.searchsorted(pos_a, k, side="left").astype(jnp.int32)
+
+
+def _padded_len(n_raw: int) -> int:
+    return max(TILE, -(-n_raw // TILE) * TILE)
+
+
+def _pad(keys, vals, pad_to):
+    """Pad the last axis with KEY_MAX keys / zero values to ``pad_to``."""
+    pad = [(0, 0)] * (keys.ndim - 1) + [(0, pad_to - keys.shape[-1])]
+    return (jnp.pad(keys, pad, constant_values=KEY_MAX32),
+            jnp.pad(vals, pad, constant_values=0))
+
+
+def _call(a_keys, a_vals, b_keys, b_vals, *, interpret: bool):
+    """Merge R pairs of runs given as ``(R, n)`` / ``(R, m)`` arrays."""
+    R, n_raw = a_keys.shape
+    n, m = _padded_len(n_raw), _padded_len(b_keys.shape[1])
+    # two KEY_MAX guard tiles past each run: window blocks never leave it.
+    a_keys, a_vals = _pad(a_keys, a_vals, n + 2 * TILE)
+    b_keys, b_vals = _pad(b_keys, b_vals, m + 2 * TILE)
+    tiles = (n + m) // TILE
+    splits = jax.vmap(functools.partial(_diagonal_splits, n=n, m=m))(
+        a_keys, b_keys).reshape(-1)
+    b_keys, b_vals = b_keys[:, ::-1], b_vals[:, ::-1]
+
+    def blk(r, t, sp, *, run_a: bool, second: int):
+        i0 = sp[r * tiles + t]
+        start = i0 if run_a else m + TILE - (t * TILE - i0)
+        # b's window starts at m + TILE when j0 = 0: aligned, so its second
+        # block is unused, and the clamp keeps that DMA inside the run.
+        last = (n if run_a else m) // TILE + 1
+        return (r, jnp.minimum(start // TILE + second, last), 0)
+
+    specs = [pl.BlockSpec((None, SUBLANES, LANES),
+                          functools.partial(blk, run_a=run_a, second=second))
+             for run_a in (True, False) for _ in ("keys", "vals")
+             for second in (0, 1)]
+    out_spec = pl.BlockSpec((None, SUBLANES, LANES),
+                            lambda r, t, sp: (r, t, 0))
+    rows = lambda x: x.reshape(R, -1, LANES)
+    ok, ov = pl.pallas_call(
+        functools.partial(_merge_kernel, n=n, m=m, tiles=tiles),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R, tiles), in_specs=specs,
+            out_specs=[out_spec, out_spec]),
+        out_shape=[
+            jax.ShapeDtypeStruct((R, (n + m) // LANES, LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((R, (n + m) // LANES, LANES), a_vals.dtype),
+        ],
+        interpret=interpret,
+    )(splits, rows(a_keys), rows(a_keys), rows(a_vals), rows(a_vals),
+      rows(b_keys), rows(b_keys), rows(b_vals), rows(b_vals))
+    return ok.reshape(R, n + m), ov.reshape(R, n + m)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -102,44 +216,9 @@ def merge_sorted(a_keys, a_vals, b_keys, b_vals, *, interpret: bool = True):
     KEY_MAX padding at the tail.  ``interpret=True`` runs the kernel body on
     CPU; pass False on real TPU.
     """
-    n_raw, m_raw = a_keys.shape[0], b_keys.shape[0]
-    n = max(TILE, -(-n_raw // TILE) * TILE)
-    m = max(TILE, -(-m_raw // TILE) * TILE)
-    a_keys, a_vals = _pad_run(a_keys, a_vals, n)
-    b_keys, b_vals = _pad_run(b_keys, b_vals, m)
-
-    total = n + m
-    steps = math.ceil(math.log2(max(n, m) + 1)) + 1
-    kernel = functools.partial(_merge_kernel, n=n, m=m, steps=steps)
-
-    a2 = a_keys.reshape(n // LANES, LANES)
-    b2 = b_keys.reshape(m // LANES, LANES)
-    av2 = a_vals.reshape(n // LANES, LANES)
-    bv2 = b_vals.reshape(m // LANES, LANES)
-
-    full = lambda rows: pl.BlockSpec((rows, LANES), lambda t: (0, 0))
-    out_spec = pl.BlockSpec((SUBLANES, LANES), lambda t: (t, 0))
-    ok, ov = pl.pallas_call(
-        kernel,
-        grid=(total // TILE,),
-        in_specs=[full(n // LANES), full(n // LANES), full(m // LANES), full(m // LANES)],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((total // LANES, LANES), jnp.uint32),
-            jax.ShapeDtypeStruct((total // LANES, LANES), a_vals.dtype),
-        ],
-        interpret=interpret,
-    )(a2, av2, b2, bv2)
-    return ok.reshape(-1), ov.reshape(-1)
-
-
-def _pad_runs_2d(keys, vals, pad_to):
-    n = keys.shape[1]
-    if n == pad_to:
-        return keys, vals
-    pad = ((0, 0), (0, pad_to - n))
-    return (jnp.pad(keys, pad, constant_values=KEY_MAX32),
-            jnp.pad(vals, pad, constant_values=0))
+    ok, ov = _call(a_keys[None], a_vals[None], b_keys[None], b_vals[None],
+                   interpret=interpret)
+    return ok[0], ov[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -154,36 +233,5 @@ def merge_sorted_batch(a_keys, a_vals, b_keys, b_vals, *, interpret: bool = True
     cascade merge all <= f children of a node in a single device dispatch
     instead of one launch per child.
     """
-    R, n_raw = a_keys.shape
-    m_raw = b_keys.shape[1]
-    assert b_keys.shape[0] == R
-    n = max(TILE, -(-n_raw // TILE) * TILE)
-    m = max(TILE, -(-m_raw // TILE) * TILE)
-    a_keys, a_vals = _pad_runs_2d(a_keys, a_vals, n)
-    b_keys, b_vals = _pad_runs_2d(b_keys, b_vals, m)
-
-    total = n + m
-    steps = math.ceil(math.log2(max(n, m) + 1)) + 1
-    kernel = functools.partial(_merge_kernel, n=n, m=m, steps=steps,
-                               batched=True)
-
-    a2 = a_keys.reshape(R, n // LANES, LANES)
-    b2 = b_keys.reshape(R, m // LANES, LANES)
-    av2 = a_vals.reshape(R, n // LANES, LANES)
-    bv2 = b_vals.reshape(R, m // LANES, LANES)
-
-    full = lambda rows: pl.BlockSpec((1, rows, LANES), lambda r, t: (r, 0, 0))
-    out_spec = pl.BlockSpec((1, SUBLANES, LANES), lambda r, t: (r, t, 0))
-    ok, ov = pl.pallas_call(
-        kernel,
-        grid=(R, total // TILE),
-        in_specs=[full(n // LANES), full(n // LANES),
-                  full(m // LANES), full(m // LANES)],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, total // LANES, LANES), jnp.uint32),
-            jax.ShapeDtypeStruct((R, total // LANES, LANES), a_vals.dtype),
-        ],
-        interpret=interpret,
-    )(a2, av2, b2, bv2)
-    return ok.reshape(R, total), ov.reshape(R, total)
+    assert b_keys.shape[0] == a_keys.shape[0]
+    return _call(a_keys, a_vals, b_keys, b_vals, interpret=interpret)

@@ -1,9 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Single dispatch point: on TPU the kernels compile natively; everywhere else
-they run under ``interpret=True`` (the Pallas interpreter executes the kernel
-body on CPU), so all call sites — the NB-tree device tier, the serving
-engine, tests, benchmarks — use exactly one code path.
+Single dispatch point: on TPU the kernels compile natively; on the CPU
+they run under ``interpret=True`` (the Pallas interpreter executes the
+kernel body), so all call sites — the NB-tree device tier, the serving
+engine, tests, benchmarks — use exactly one code path.  Any other backend
+is an error: there is no silent fallback that hides the device.
 """
 from __future__ import annotations
 
@@ -19,7 +20,11 @@ from .sorted_search import sorted_search as _sorted_search
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run natively on 'tpu' and "
+                           f"interpreted on 'cpu'; backend is {backend!r}")
+    return backend == "cpu"
 
 
 def merge_sorted(a_keys, a_vals, b_keys, b_vals):

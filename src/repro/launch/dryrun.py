@@ -38,6 +38,9 @@ from ..serve import steps as serve_steps
 from ..train.train_step import make_train_step
 from .mesh import make_production_mesh, mesh_context
 
+#: the production meshes are v5e pods (see launch/mesh.py).
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 # ---------------------------------------------------------------- input specs
 def input_specs(cfg, shape):
@@ -176,8 +179,6 @@ def cache_specs(cfg, shape, mesh, cache_shapes):
 # ---------------------------------------------------------- cost correction
 def _raw_costs(compiled):
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict per device kind
-        ca = ca[0] if ca else {}
     wires = analysis.collective_wire_bytes(compiled.as_text())
     return np.array([float(ca.get("flops", 0.0)),
                      float(ca.get("bytes accessed", 0.0)),
@@ -302,7 +303,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *, microbatches: int = 1,
     roof = analysis.analyze_from(
         flops=flops, hbm_bytes=bytes_acc, ici_bytes=ici, dcn_bytes=dcn,
         peak_mem=peak, n_devices=n_dev, model_flops_total=mf,
-        by_kind=analysis.collective_wire_bytes(compiled.as_text())["by_kind"])
+        by_kind=analysis.collective_wire_bytes(compiled.as_text())["by_kind"],
+        device_kind=TARGET_DEVICE_KIND)
     rec = {
         "arch": arch, "shape": shape_name, "status": "ok",
         "mesh": "x".join(str(v) for v in mesh.shape.values()),

@@ -12,39 +12,27 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """axis_types only where the installed jax has it (added after 0.4.x)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
-
-
 def mesh_context(mesh):
-    """``jax.sharding.set_mesh(mesh)`` on current jax; the legacy ``Mesh``
-    context manager (same thread-local resource env) on 0.4.x."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """``jax.sharding.set_mesh(mesh)``: the mesh of everything traced inside."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def current_mesh():
-    """``jax.sharding.get_abstract_mesh()`` on current jax; the thread-local
-    physical mesh (what the ``Mesh`` context manager sets) on 0.4.x."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src.mesh import thread_resources
-    return thread_resources.env.physical_mesh
+    """The abstract mesh set by :func:`mesh_context` (empty outside one)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests, elastic resize)."""
     return jax.make_mesh(
-        tuple(shape), tuple(axes), **_axis_type_kwargs(len(axes)))
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def host_device_flag(n: int = 512) -> str:

@@ -32,10 +32,7 @@ def quantized_psum_mean(tree, error, axis: str = "pod", bits: int = 8):
     Returns (reduced_tree, new_error_tree).
     """
     qmax = float(2 ** (bits - 1) - 1)
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis)
-    else:                               # jax 0.4.x spelling
-        n = jax.lax.psum(1, axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         x = g.astype(jnp.float32) + e

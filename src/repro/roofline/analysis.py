@@ -152,11 +152,14 @@ def active_params(cfg) -> float:
 
 def analyze_from(*, flops: float, hbm_bytes: float, ici_bytes: float,
                  dcn_bytes: float, peak_mem: int, n_devices: int,
-                 model_flops_total: float, by_kind: dict) -> Roofline:
-    """Roofline from (possibly trip-count-corrected) per-device totals."""
-    t_c = flops / hw.PEAK_FLOPS_BF16
-    t_m = hbm_bytes / hw.HBM_BW
-    t_x = ici_bytes / hw.ICI_BW_PER_LINK + dcn_bytes / hw.DCN_BW_PER_HOST
+                 model_flops_total: float, by_kind: dict,
+                 device_kind: str) -> Roofline:
+    """Roofline from (possibly trip-count-corrected) per-device totals,
+    against the published peaks of ``device_kind``."""
+    chip = hw.peaks(device_kind)
+    t_c = flops / chip.flops_bf16
+    t_m = hbm_bytes / chip.hbm_bw
+    t_x = ici_bytes / chip.ici_bw_per_link + dcn_bytes / hw.DCN_BW_PER_HOST
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     bottleneck = max(terms, key=terms.get)
     useful = model_flops_total / max(1.0, flops * n_devices)
@@ -165,8 +168,7 @@ def analyze_from(*, flops: float, hbm_bytes: float, ici_bytes: float,
                     peak_mem, by_kind)
 
 
-def measured_kernel_table(dispatch_stats: dict, *,
-                          peak_bw: float = hw.HBM_BW) -> list:
+def measured_kernel_table(dispatch_stats: dict, *, device_kind: str) -> list:
     """Measured per-kernel achieved bandwidth from tracer dispatch stats.
 
     ``dispatch_stats`` is ``NBTreeIndex.dispatch_stats`` — populated when a
@@ -174,11 +176,13 @@ def measured_kernel_table(dispatch_stats: dict, *,
     mapping kernel name to ``{count, wall_s, bytes}`` where ``bytes`` is
     the argument+result footprint moved per dispatch (a lower bound on
     HBM traffic: internal scratch isn't counted).  Each returned row adds
-    the achieved GB/s and its fraction of ``peak_bw``, sorted by total
+    the achieved GB/s and its fraction of ``device_kind``'s peak HBM
+    bandwidth, sorted by total
     wall time — the empirical counterpart of the analytic ``t_memory``
     term, so the dry-run roofline and a real run are directly comparable
     per kernel.
     """
+    peak_bw = hw.peaks(device_kind).hbm_bw
     rows = []
     for name, st in dispatch_stats.items():
         wall = float(st.get("wall_s", 0.0))
@@ -190,14 +194,14 @@ def measured_kernel_table(dispatch_stats: dict, *,
             "wall_s": wall,
             "bytes": int(nbytes),
             "achieved_gb_s": bw / 1e9,
-            "peak_frac": bw / peak_bw if peak_bw > 0 else 0.0,
+            "peak_frac": bw / peak_bw,
         })
     rows.sort(key=lambda r: r["wall_s"], reverse=True)
     return rows
 
 
 def analyze(compiled, *, n_devices: int, model_flops_total: float,
-            pod_stride: int = 256) -> Roofline:
+            device_kind: str, pod_stride: int = 256) -> Roofline:
     """Single-artifact roofline (no scan correction — see dryrun for that)."""
     ca = compiled.cost_analysis()
     wires = collective_wire_bytes(compiled.as_text(), pod_stride)
@@ -209,4 +213,4 @@ def analyze(compiled, *, n_devices: int, model_flops_total: float,
         hbm_bytes=float(ca.get("bytes accessed", 0.0)),
         ici_bytes=wires["ici"], dcn_bytes=wires["dcn"], peak_mem=peak,
         n_devices=n_devices, model_flops_total=model_flops_total,
-        by_kind=wires["by_kind"])
+        by_kind=wires["by_kind"], device_kind=device_kind)

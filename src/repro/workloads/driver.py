@@ -44,6 +44,7 @@ import os
 
 import numpy as np
 
+from repro.compile_cache import place_compile_cache
 from repro.core.engine_api import (FIVE_TIERS, OpKind, StorageEngine,
                                    available_engines, make_engine)
 from repro.obs.metrics import BUCKET_EDGES_S, LogBucketHistogram, ObsConfig
@@ -473,6 +474,7 @@ def main(argv=None) -> None:
     if args.dist:
         overrides["dist"] = args.dist
 
+    place_compile_cache()
     reports = []
     for name in names:
         base_kw = _SMALL_CONFIGS.get(name, {})

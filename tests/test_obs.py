@@ -325,11 +325,26 @@ def test_measured_kernel_table_from_dispatch_stats():
         "_flush_impl": {"count": 4, "wall_s": 2.0, "bytes": 8_190_000_000},
         "_insert_impl": {"count": 100, "wall_s": 0.1, "bytes": 1_000_000},
     }
-    rows = measured_kernel_table(stats, peak_bw=819e9)
+    rows = measured_kernel_table(stats, device_kind="TPU v5 lite")
     assert [r["kernel"] for r in rows] == ["_flush_impl", "_insert_impl"]
     assert rows[0]["achieved_gb_s"] == pytest.approx(4.095)
     assert rows[0]["peak_frac"] == pytest.approx(0.005)
     assert rows[1]["count"] == 100
     zero = measured_kernel_table({"k": {"count": 1, "wall_s": 0.0,
-                                        "bytes": 10}})
+                                        "bytes": 10}},
+                                 device_kind="TPU v5 lite")
     assert zero[0]["achieved_gb_s"] == 0.0
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    from repro.roofline import hardware as hw
+    from repro.roofline.analysis import measured_kernel_table
+
+    v5e = hw.peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                           16 * 10**9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="cpu"):
+        hw.peaks("cpu")
+    with pytest.raises(KeyError):
+        measured_kernel_table({}, device_kind="TPU v4")

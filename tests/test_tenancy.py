@@ -442,9 +442,11 @@ def test_driver_multi_stream_open_loop():
     assert ol["n_done"] == 400
 
 
-def test_driver_cli_multi_mix(tmp_path, capsys):
+def test_driver_cli_multi_mix(tmp_path, capsys, monkeypatch):
     from repro.workloads import driver
 
+    # the CLI places JAX's compile cache; keep it out of the checkout
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
     out = tmp_path / "multi.json"
     driver.main(["--engines", "nbtree", "--mix", "insert-heavy",
                  "--mix", "point-read-heavy", "--ops", "200", "--batch",

@@ -1,0 +1,7 @@
+"""apply_us_per_op.serve: engine adapter, host time in a synced
+``StorageEngine.apply`` per op (benchmark spans)."""
+from bench.readers import apply_us_per_op
+
+
+def read(run):
+    return apply_us_per_op(run)

@@ -239,6 +239,10 @@ class EngineStats:
     #: in one process count independently, unlike the former module-global
     #: shim.  Sim tiers report 0.
     device_dispatches: int = 0
+    #: device->host waits and reads of the device tier's fused path
+    #: (``NBTreeIndex.sync_count``; sharded ensembles sum).  Sim tiers
+    #: report 0.
+    device_syncs: int = 0
     #: highest WAL commit LSN applied to this engine (0 = never ran under a
     #: durable frontend).  Written by the durable ingest path via
     #: :meth:`StorageEngine.note_applied`; the recovery invariant is that a
@@ -303,10 +307,11 @@ class StorageEngine(abc.ABC):
 
         Base implementation is a no-op — engines with nothing structured
         to report (the scalar cost-model tiers) simply ignore it.  The
-        device adapter forwards it to the kernel dispatch funnel
-        (per-dispatch + flush-unit spans); sharded ensembles forward to
-        every shard and emit split/debt events themselves.  Called by the
-        ingest frontends when observability is enabled.
+        device adapter records ``nbtree.*`` spans (``Tracer.span``: the
+        ring buffer and a running profiler's trace); sharded ensembles
+        forward to every shard and emit split/debt events themselves.
+        ``None`` detaches.  Called by the ingest frontends when
+        observability is enabled.
         """
 
     # ------------------------------------------------------------- maintenance
@@ -555,10 +560,15 @@ class BulkBTreeEngine(CostModelEngine):
 
 
 # ================================================================ device tier
+def _pow2(n: int) -> int:
+    """The power-of-two bucket ``_pad_pow2`` pads a run of ``n`` ops to."""
+    return 1 << max(0, n - 1).bit_length()
+
+
 def _pad_pow2(a: np.ndarray) -> np.ndarray:
     """Pad a 1-d array to the next power-of-two length by repeating a[-1]."""
     n = len(a)
-    target = 1 << max(0, n - 1).bit_length()
+    target = _pow2(n)
     if n in (0, target):
         return a
     return np.concatenate([a, np.repeat(a[-1:], target - n)])
@@ -579,6 +589,12 @@ class DeviceNBTreeEngine(StorageEngine):
     repeat the last op verbatim, a blind re-write of the same (key, value)
     that newest-wins dedup makes logically invisible (the physical duplicate
     is retired at the next leaf compaction, like any stale copy).
+
+    With a tracer attached (:meth:`attach_tracer`), ``apply`` records an
+    ``nbtree.apply`` span (``ops``, ``seq``: this engine's commit number)
+    holding one ``nbtree.run`` span per same-kind run (``kind``, ``n``,
+    ``padded``), and the index adds its ``nbtree.unit`` /
+    ``nbtree.dispatch`` / ``nbtree.sync`` spans (``core/jax_nbtree.py``).
     """
 
     name = "jax-nbtree"
@@ -600,20 +616,24 @@ class DeviceNBTreeEngine(StorageEngine):
         # p50/p99 — so long-running servers pay O(1) per unit and per
         # stats() snapshot.
         self._maintain_unit_s = LogBucketHistogram()
-        self._t_origin = time.perf_counter()
+        self._commits = 0     # apply calls: the ``seq`` of nbtree.apply
         self._tracer = None
 
     # ------------------------------------------------------------------ apply
     def apply(self, batch: OpBatch) -> OpResult:
-        import jax
+        self._commits += 1
+        if self._tracer is None:
+            return self._apply(batch)
+        with self._tracer.span("dispatch", "nbtree.apply", ops=len(batch),
+                               seq=self._commits):
+            return self._apply(batch)
 
+    def _apply(self, batch: OpBatch) -> OpResult:
         n = len(batch)
-        found = np.zeros(n, bool)
-        values = np.full(n, -1, VAL_DTYPE)
-        range_hits: list = [None] * n
-        truncated = np.zeros(n, bool)
-        lat = np.zeros(n, np.float64)
         kinds = np.asarray(batch.kinds)
+        res = OpResult(kinds.copy(), np.zeros(n, bool),
+                       np.full(n, -1, VAL_DTYPE), [None] * n,
+                       np.zeros(n, np.float64), np.zeros(n, bool))
         i = 0
         while i < n:
             j = i + 1
@@ -621,50 +641,64 @@ class DeviceNBTreeEngine(StorageEngine):
                 j += 1
             kind = OpKind(int(kinds[i]))
             sl = slice(i, j)
-            real = j - i
             t0 = time.perf_counter()
-            if kind is OpKind.INSERT:
-                self.idx.insert_batch(
-                    _pad_pow2(batch.keys[sl].astype(np.uint32)),
-                    _pad_pow2(batch.vals[sl].astype(np.int32)))
-                jax.block_until_ready(self.idx.run_keys)
-            elif kind is OpKind.DELETE:
-                self.idx.delete_batch(_pad_pow2(batch.keys[sl].astype(np.uint32)))
-                jax.block_until_ready(self.idx.run_keys)
-            elif kind is OpKind.QUERY:
-                pres, vals = self.idx.query_batch(
-                    _pad_pow2(batch.keys[sl].astype(np.uint32)))
-                pres = np.asarray(pres)[:real]
-                vals = np.asarray(vals)[:real]
-                found[sl] = pres
-                values[sl] = np.where(pres, vals.astype(np.int64), -1)
+            if self._tracer is None:
+                self._run(kind, batch, sl, res)
             else:
-                self._apply_ranges(batch, sl, range_hits, truncated)
+                with self._tracer.span("dispatch", "nbtree.run",
+                                       kind=kind.name.lower(), n=j - i,
+                                       padded=_pow2(j - i)):
+                    self._run(kind, batch, sl, res)
             dt = time.perf_counter() - t0
             self._wall_s += dt
-            lat[sl] = dt / (j - i)
+            res.latency_s[sl] = dt / (j - i)
             self._counts[kind] += j - i
             i = j
-        return OpResult(kinds.copy(), found, values, range_hits, lat,
-                        truncated)
+        return res
 
-    def _apply_ranges(self, batch: OpBatch, sl: slice, range_hits: list,
-                      truncated: np.ndarray) -> None:
-        los = _pad_pow2(batch.keys[sl].astype(np.uint32))
+    def _run(self, kind: OpKind, batch: OpBatch, sl: slice,
+             res: OpResult) -> None:
+        """One same-kind run ``batch[sl]``: pad, put, call, and wait for
+        (or read back) its results into ``res``."""
+        import jax
+
+        idx = self.idx
+        keys = _pad_pow2(batch.keys[sl].astype(np.uint32))
+        if kind is OpKind.INSERT:
+            idx.insert_batch(keys, _pad_pow2(batch.vals[sl].astype(np.int32)))
+            idx._fetch("ack", idx.run_keys, jax.block_until_ready)
+        elif kind is OpKind.DELETE:
+            idx.delete_batch(keys)
+            idx._fetch("ack", idx.run_keys, jax.block_until_ready)
+        elif kind is OpKind.QUERY:
+            real = sl.stop - sl.start
+            pres, vals = idx.query_batch(keys)
+            pres = idx._fetch("found", pres)[:real]
+            vals = idx._fetch("values", vals)[:real]
+            res.found[sl] = pres
+            res.values[sl] = np.where(pres, vals.astype(np.int64), -1)
+        else:
+            self._apply_ranges(keys, batch, sl, res)
+
+    def _apply_ranges(self, los: np.ndarray, batch: OpBatch, sl: slice,
+                      res: OpResult) -> None:
+        idx = self.idx
         his = _pad_pow2(batch.his[sl].astype(np.uint32))
         while True:
-            rk, rv, cnt, trunc = self.idx.range_query_batch(
+            rk, rv, cnt, trunc = idx.range_query_batch(
                 los, his, max_results=self._max_results)
-            trunc = np.asarray(trunc)
+            trunc = idx._fetch("range_truncated", trunc)
             if not trunc.any() or self._max_results >= (1 << 20):
                 break
             self._max_results *= 2      # sticky: later batches start larger
-        rk, rv, cnt = np.asarray(rk), np.asarray(rv), np.asarray(cnt)
+        rk = idx._fetch("range_keys", rk)
+        rv = idx._fetch("range_vals", rv)
+        cnt = idx._fetch("range_count", cnt)
         for b in range(sl.stop - sl.start):
             c = int(cnt[b])
-            range_hits[sl.start + b] = (rk[b, :c].astype(KEY_DTYPE),
-                                        rv[b, :c].astype(VAL_DTYPE))
-            truncated[sl.start + b] = bool(trunc[b])
+            res.range_hits[sl.start + b] = (rk[b, :c].astype(KEY_DTYPE),
+                                            rv[b, :c].astype(VAL_DTYPE))
+            res.range_truncated[sl.start + b] = bool(trunc[b])
 
     # ------------------------------------------------------------- maintenance
     def maintain(self, budget: int = 1) -> int:
@@ -687,11 +721,6 @@ class DeviceNBTreeEngine(StorageEngine):
             self._wall_s += dt
             if self.idx.units_done > u0:   # not a stale-entry-only pop
                 self._maintain_unit_s.add(dt)
-                if self._tracer is not None:
-                    self._tracer.complete(
-                        "flush_unit", "maintain_unit",
-                        t0 - self._t_origin, dt,
-                        unit=int(self.idx.units_done))
         return pending
 
     def drain(self) -> None:
@@ -726,12 +755,11 @@ class DeviceNBTreeEngine(StorageEngine):
         return self.idx.height
 
     def attach_tracer(self, tracer) -> None:
-        """Forward to the kernel layer: per-dispatch spans flow from the
-        ``NBTreeIndex`` dispatch funnel, flush-unit spans from
-        :meth:`maintain` — both on this engine's wall clock (seconds since
-        engine construction)."""
+        """Record ``nbtree.apply`` / ``nbtree.run`` spans here and forward
+        to the index for its unit, dispatch and sync spans (class
+        docstring); ``None`` detaches both."""
         self._tracer = tracer
-        self.idx.attach_tracer(tracer, t_origin=self._t_origin)
+        self.idx.attach_tracer(tracer)
 
     def stats(self) -> EngineStats:
         mu = self._maintain_unit_s
@@ -754,6 +782,7 @@ class DeviceNBTreeEngine(StorageEngine):
             maintain_unit_p99_s=mu.quantile(0.99),
             maintain_unit_p100_s=mu.max if mu.count else 0.0,
             device_dispatches=self.idx.dispatch_count,
+            device_syncs=self.idx.sync_count,
             applied_lsn=self.applied_lsn)
 
 

@@ -40,12 +40,17 @@ device dispatch:
 Host control metadata (node id, child ids, pivots) is routed in as scalars
 and tiny arrays; the only device->host traffic per flush is the returned
 (<= f+1)-element count vector.  Every device computation the index launches
-goes through the ``_device_call`` funnel, so dispatch budgets are
-observable (per-instance ``dispatch_count`` / ``dispatch_stats``, plus
-optional per-dispatch tracer spans) and regression-tested.  The pre-fusion
-eager path is kept under ``fused=False`` as the differential-testing and
-benchmarking baseline (``benchmarks/bench_ingest_device.py`` measures the
-before/after).
+goes through the ``_device_call`` funnel (``_dispatch``), and every
+device->host wait or read of the fused path through ``_fetch``, so dispatch
+and sync budgets are observable (per-instance ``dispatch_count`` and
+``sync_count``) and regression-tested.  With a
+:class:`repro.obs.trace.Tracer` attached, each maintenance unit, dispatch
+and sync is also a span (``nbtree.unit`` / ``nbtree.dispatch`` /
+``nbtree.sync``) in the ring buffer and in a running profiler's trace.
+The pre-fusion eager path is kept under ``fused=False`` as the
+differential-testing and benchmarking baseline
+(``benchmarks/bench_ingest_device.py`` measures the before/after); its own
+reads do not go through ``_fetch``.
 
 Range queries (DESIGN.md §4): ``range_query_batch(lo, hi, max_results)``
 serves inclusive scans ``[lo, hi]`` with the same host/device split as point
@@ -79,7 +84,6 @@ from __future__ import annotations
 
 import functools
 import math
-import time
 from collections import Counter, deque
 
 import jax
@@ -107,13 +111,6 @@ def _device_call(fn, *args, **kwargs):
     share mutable global state.
     """
     return fn(*args, **kwargs)
-
-
-def _tree_nbytes(x) -> int:
-    """Total array bytes in a (possibly nested) dispatch input/output."""
-    if isinstance(x, (tuple, list)):
-        return sum(_tree_nbytes(e) for e in x)
-    return int(getattr(x, "nbytes", 0))
 
 
 def _round_up(x: int, m: int) -> int:
@@ -526,41 +523,42 @@ class NBTreeIndex:
         #: device dispatches issued by THIS index (per-instance; surfaced
         #: as ``EngineStats.device_dispatches``).
         self.dispatch_count = 0
-        #: per-impl measured totals ``{name: {count, wall_s, bytes}}``,
-        #: populated only while a tracer is attached (the roofline
-        #: measured-bandwidth source; see repro.roofline.analysis).
-        self.dispatch_stats: dict = {}
+        #: device->host waits and reads through ``_fetch`` (surfaced as
+        #: ``EngineStats.device_syncs``).
+        self.sync_count = 0
         self._tracer = None
-        self._t_origin = 0.0
 
     # ------------------------------------------------------------ dispatch
-    def attach_tracer(self, tracer, *, t_origin: float | None = None) -> None:
-        """Record per-dispatch wall spans (category ``dispatch``) and
-        per-impl timing/byte totals.  ``t_origin`` anchors span timestamps
-        (perf_counter seconds); defaults to attach time."""
+    def attach_tracer(self, tracer) -> None:
+        """Record ``nbtree.unit``, ``nbtree.dispatch`` and ``nbtree.sync``
+        spans on ``tracer`` (a :class:`repro.obs.trace.Tracer`); ``None``
+        detaches."""
         self._tracer = tracer
-        self._t_origin = (time.perf_counter() if t_origin is None
-                          else t_origin)
 
     def _dispatch(self, fn, *args, **kwargs):
         """Per-instance dispatch shim over the module :func:`_device_call`
         funnel (still monkeypatchable there).  Counting is always on and
-        O(1); timing + span emission only while a tracer is attached, so
-        the untraced hot path stays a counter bump."""
+        O(1); the ``nbtree.dispatch`` span only while a tracer is
+        attached, so the untraced hot path stays a counter bump."""
         self.dispatch_count += 1
         if self._tracer is None:
             return _device_call(fn, *args, **kwargs)
-        name = getattr(fn, "__name__", None) or repr(fn)
-        t0 = time.perf_counter()
-        out = _device_call(fn, *args, **kwargs)
-        dt = time.perf_counter() - t0
-        st = self.dispatch_stats.setdefault(
-            name, {"count": 0, "wall_s": 0.0, "bytes": 0})
-        st["count"] += 1
-        st["wall_s"] += dt
-        st["bytes"] += _tree_nbytes(args) + _tree_nbytes(out)
-        self._tracer.complete("dispatch", name, t0 - self._t_origin, dt)
-        return out
+        with self._tracer.span("dispatch", "nbtree.dispatch",
+                               fn=getattr(fn, "__name__", None) or repr(fn)):
+            return _device_call(fn, *args, **kwargs)
+
+    def _fetch(self, what: str, x, read=np.asarray):
+        """The sync counterpart of :meth:`_dispatch`: every device->host
+        wait or read of the fused path is ``read(x)`` here (``np.asarray``
+        by default, ``jax.block_until_ready`` for a bare wait).  ``what``
+        names it (``ack``, ``found``, ``values``, ``bloom_tallies``,
+        ``flush_counts``, ``split_out``, ``range_*``) on the
+        ``nbtree.sync`` span; ``sync_count`` counts every call."""
+        self.sync_count += 1
+        if self._tracer is None:
+            return read(x)
+        with self._tracer.span("sync", "nbtree.sync", what=what):
+            return read(x)
 
     # --------------------------------------------------------- pending queue
     def _enqueue(self, node: _HostNode, front: bool = False) -> None:
@@ -644,9 +642,9 @@ class NBTreeIndex:
             self.run_keys, self.run_vals, self.run_count, self.bloom, q,
             f=self.f, levels=self.max_levels, run_cap=self.run_cap,
             nbits=self.nbits, h=self.h, steps=self._steps)
-        self.bloom_probes += int(n_probe)
-        self.bloom_negative_skips += int(n_neg)
-        self.bloom_false_positives += int(n_fp)
+        self.bloom_probes += int(self._fetch("bloom_tallies", n_probe))
+        self.bloom_negative_skips += int(self._fetch("bloom_tallies", n_neg))
+        self.bloom_false_positives += int(self._fetch("bloom_tallies", n_fp))
         return present, out
 
     def range_query_batch(self, lo, hi, max_results: int = 256):
@@ -718,8 +716,31 @@ class NBTreeIndex:
             node = self._dequeue()
             if node.count <= self.sigma:
                 continue
-            units += self._handle_full(node)
+            if self._tracer is None:
+                units += self._handle_full(node)
+            else:
+                with self._tracer.span("flush_unit", "nbtree.unit",
+                                       kind=self._unit_kind(node),
+                                       pairs=node.count):
+                    units += self._handle_full(node)
         return len(self._pending)
+
+    def _unit_kind(self, node: _HostNode) -> str:
+        """What :meth:`_handle_full` will do to ``node``: ``flush`` an
+        internal node; ``split_root`` the root leaf (the first split);
+        ``split_leaf`` a leaf whose parent absorbs the new sibling;
+        ``split_internal`` when that overflows internal ancestors too; and
+        ``grow`` when the cascade reaches the root (height + 1)."""
+        if not node.is_leaf:
+            return "flush"
+        if node is self.root:
+            return "split_root"
+        kind, anc = "split_leaf", node.parent
+        while len(anc.children) >= self.f:   # one more child overflows it
+            if anc is self.root:
+                return "grow"
+            kind, anc = "split_internal", anc.parent
+        return kind
 
     def drain(self) -> None:
         while self.maintain(64):
@@ -779,7 +800,7 @@ class NBTreeIndex:
             nc=nc, leaf=node.children[0].is_leaf, sigma=self.sigma,
             sigma_pad=self.sigma_pad, run_cap=self.run_cap,
             nbits=self.nbits, h=self.h, interpret=ops._interpret())
-        counts = np.asarray(counts)      # the flush's one device->host sync
+        counts = self._fetch("flush_counts", counts)  # the flush's one sync
         for child, c in zip(node.children, counts[:-1].tolist()):
             child.count = int(c)
             assert child.count <= self.run_cap, "child run overflow"
@@ -919,7 +940,7 @@ class NBTreeIndex:
                 jnp.uint32(at_key if has_key else 0),
                 has_key=has_key, run_cap=self.run_cap, nbits=self.nbits,
                 h=self.h)
-            out = np.asarray(out)        # the split's one device->host sync
+            out = self._fetch("split_out", out)  # the split's one sync
             k_m, cut = int(out[0]), int(out[1])
             left.count, right.count = cut, node.count - cut
             return k_m

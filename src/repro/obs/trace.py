@@ -8,11 +8,20 @@ multi-hour run keeps the most recent ``capacity`` events instead of
 growing without bound, and ``dropped_events`` records how many fell off
 the head so a truncated trace is never mistaken for a complete one.
 
-The tracer is clock-agnostic: callers pass explicit timestamps in
-*seconds* on whichever clock owns the component (sim seconds in the
-ingest frontends, wall seconds in the device engine), so sim-tier traces
-are byte-deterministic.  There is no global "now" — determinism would die
-the moment a span implicitly read ``time.time()``.
+:meth:`Tracer.complete` and :meth:`Tracer.instant` are clock-agnostic:
+callers pass explicit timestamps in *seconds* on whichever clock owns the
+component (sim seconds in the ingest frontends and shard ensemble), so
+sim-tier traces are byte-deterministic.  There is no global "now" —
+determinism would die the moment such a span implicitly read
+``time.time()``.
+
+:meth:`Tracer.span` is the one wall-clock recorder, used by the device
+tier (``DeviceNBTreeEngine``, ``NBTreeIndex``).  It writes each span to two
+sinks: a ``jax.profiler.TraceAnnotation`` of the same name and arguments,
+which a running profiler session records on the device trace's clock, and
+this ring buffer on ``time.perf_counter`` seconds since the tracer was
+made.  A device-tier call site with no tracer attached pays one attribute
+check: it builds no annotation and reads no clock.
 
 Span categories are a closed vocabulary (:data:`SPAN_CATEGORIES`) so the
 stall attributor and trace consumers can rely on the set.
@@ -20,7 +29,9 @@ stall attributor and trace consumers can rely on the set.
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
+import time
 
 #: Closed span-category vocabulary.  ``pid`` in the emitted JSON is the
 #: category's index here, giving each category its own named process row
@@ -39,6 +50,7 @@ SPAN_CATEGORIES = (
     "chaos",           # injected fault event (instant; repro.wal.faults)
     "failover",        # primary death -> writes restored (replication)
     "catchup",         # replica rebuild: snapshot ship + WAL tail replay
+    "sync",            # one device->host wait or read (device tier)
 )
 
 _CAT_INDEX = {c: i for i, c in enumerate(SPAN_CATEGORIES)}
@@ -50,6 +62,12 @@ class Tracer:
     All times are seconds; the emitted JSON converts to the format's
     microseconds.  ``enabled=False`` turns every method into an immediate
     no-op so a disabled tracer can be threaded unconditionally.
+
+    On the device tier (``attach_tracer`` of ``DeviceNBTreeEngine``,
+    ``NBTreeIndex`` or ``ShardedEngine``) every span also lands in the
+    profiler trace, when a profiler session runs, beside the device's
+    programs; ``attach_tracer(None)`` detaches, after which each span site
+    costs one attribute check.
     """
 
     def __init__(self, capacity: int = 1 << 16, *, enabled: bool = True):
@@ -58,8 +76,17 @@ class Tracer:
         self._events: collections.deque = collections.deque(
             maxlen=self.capacity)
         self._seen = 0  # total events ever recorded (>= len(_events))
+        self.wall_origin_s = time.perf_counter()  # t = 0 of span()
 
     # -- recording ---------------------------------------------------------
+    def span(self, cat: str, name: str, **args):
+        """Context manager timing a wall-clock span on both sinks (module
+        docstring): a profiler ``TraceAnnotation(name, **args)`` and a
+        complete event in this buffer.  A disabled tracer records neither."""
+        if not self.enabled:
+            return _NO_SPAN
+        return _Span(self, cat, name, args)
+
     def complete(self, cat: str, name: str, t0_s: float, dur_s: float,
                  **args) -> None:
         """Record a completed span [t0_s, t0_s + dur_s)."""
@@ -127,6 +154,35 @@ class Tracer:
     def clear(self) -> None:
         self._events.clear()
         self._seen = 0
+
+
+class _Span:
+    """One :meth:`Tracer.span`: the annotation opens first and closes last,
+    so the profiler's interval holds the buffer's."""
+
+    __slots__ = ("tracer", "cat", "name", "args", "ann", "t0")
+
+    def __init__(self, tracer: Tracer, cat: str, name: str, args: dict):
+        self.tracer, self.cat, self.name, self.args = tracer, cat, name, args
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self.ann = TraceAnnotation(self.name, **self.args)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        tr = self.tracer
+        tr.complete(self.cat, self.name, self.t0 - tr.wall_origin_s,
+                    t1 - self.t0, **self.args)
+        return False
+
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 def validate_chrome_trace(obj: dict) -> list[str]:

@@ -168,38 +168,6 @@ def analyze_from(*, flops: float, hbm_bytes: float, ici_bytes: float,
                     peak_mem, by_kind)
 
 
-def measured_kernel_table(dispatch_stats: dict, *, device_kind: str) -> list:
-    """Measured per-kernel achieved bandwidth from tracer dispatch stats.
-
-    ``dispatch_stats`` is ``NBTreeIndex.dispatch_stats`` — populated when a
-    :class:`repro.obs.trace.Tracer` is attached to the device engine —
-    mapping kernel name to ``{count, wall_s, bytes}`` where ``bytes`` is
-    the argument+result footprint moved per dispatch (a lower bound on
-    HBM traffic: internal scratch isn't counted).  Each returned row adds
-    the achieved GB/s and its fraction of ``device_kind``'s peak HBM
-    bandwidth, sorted by total
-    wall time — the empirical counterpart of the analytic ``t_memory``
-    term, so the dry-run roofline and a real run are directly comparable
-    per kernel.
-    """
-    peak_bw = hw.peaks(device_kind).hbm_bw
-    rows = []
-    for name, st in dispatch_stats.items():
-        wall = float(st.get("wall_s", 0.0))
-        nbytes = float(st.get("bytes", 0.0))
-        bw = nbytes / wall if wall > 0 else 0.0
-        rows.append({
-            "kernel": name,
-            "count": int(st.get("count", 0)),
-            "wall_s": wall,
-            "bytes": int(nbytes),
-            "achieved_gb_s": bw / 1e9,
-            "peak_frac": bw / peak_bw,
-        })
-    rows.sort(key=lambda r: r["wall_s"], reverse=True)
-    return rows
-
-
 def analyze(compiled, *, n_devices: int, model_flops_total: float,
             device_kind: str, pod_stride: int = 256) -> Roofline:
     """Single-artifact roofline (no scan correction — see dryrun for that)."""

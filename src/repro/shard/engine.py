@@ -76,8 +76,9 @@ class ShardedEngine(StorageEngine):
         self.n_splits = 0
         # monotone counters of shards retired by rebalances
         # (io_s, seeks, rd, wr, bloom probes / skips / false positives,
-        #  maintain units, maintain wall seconds, device dispatches)
-        self._retired = [0.0, 0, 0, 0, 0, 0, 0, 0, 0.0, 0]
+        #  maintain units, maintain wall seconds, device dispatches,
+        #  device syncs)
+        self._retired = [0.0, 0, 0, 0, 0, 0, 0, 0, 0.0, 0, 0]
         self._tracer = None
         if partition == "hash":
             self.partitioner = HashPartitioner(shards)
@@ -89,7 +90,8 @@ class ShardedEngine(StorageEngine):
         ensemble-level events: one ``shard_split`` instant per rebalance
         and a ``cascade`` debt-allocation instant whenever the scheduler
         hands out budget.  Event timestamps are the ensemble's *charged*
-        I/O seconds — deterministic on sim tiers, and monotone."""
+        I/O seconds — deterministic on sim tiers, and monotone.  ``None``
+        detaches the ensemble and every shard."""
         self._tracer = tracer
         for e in self._engines:
             e.attach_tracer(tracer)
@@ -277,6 +279,7 @@ class ShardedEngine(StorageEngine):
         self._retired[7] += st.maintain_units
         self._retired[8] += st.maintain_wall_s
         self._retired[9] += st.device_dispatches
+        self._retired[10] += st.device_syncs
         lineage_s = self._inherited_s[sid] + eng.io_time_s()
         left = rk < np.uint64(q)
         a, b = self._make_shard(), self._make_shard()
@@ -386,4 +389,5 @@ class ShardedEngine(StorageEngine):
                                      default=0.0),
             device_dispatches=self._retired[9] + sum(s.device_dispatches
                                                      for s in per),
+            device_syncs=self._retired[10] + sum(s.device_syncs for s in per),
             applied_lsn=self.applied_lsn)

@@ -8,8 +8,8 @@ Chrome trace_event emission (schema validity, ring-buffer bounds,
 round-trip through JSON), stall detection + attribution against a
 synthetic injected stall, byte-determinism of obs-instrumented open-loop
 reports, the disabled-mode zero-overhead contract (obs off == obs absent,
-to the byte), the driver histogram facade, and the measured per-kernel
-bandwidth table fed by tracer dispatch stats.
+to the byte), the driver histogram facade, the wall-clock ``Tracer.span``,
+and the chip peaks table.
 """
 import json
 
@@ -265,10 +265,32 @@ def test_validate_chrome_trace_catches_malformed():
     assert validate_chrome_trace({"traceEvents": []}) == []
 
 
+def test_span_records_wall_interval_with_args():
+    tr = Tracer()
+    with tr.span("sync", "nbtree.sync", what="ack"):
+        with tr.span("dispatch", "nbtree.dispatch", fn="_insert_impl"):
+            pass
+    inner, outer = tr.spans()            # recorded as each one closes
+    assert (outer["cat"], outer["name"], outer["args"]) == (
+        "sync", "nbtree.sync", {"what": "ack"})
+    assert inner["args"] == {"fn": "_insert_impl"}
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+    assert outer["pid"] == SPAN_CATEGORIES.index("sync")
+    assert not validate_chrome_trace(tr.to_chrome())
+
+
+def test_disabled_tracer_span_records_nothing():
+    tr = Tracer(enabled=False)
+    with tr.span("sync", "nbtree.sync", what="ack"):
+        pass
+    assert len(tr) == 0 and tr.dropped_events == 0
+
+
 def test_span_categories_cover_serving_pipeline():
     assert {"commit", "wal_fsync", "flush_unit", "cascade", "shard_split",
-            "checkpoint", "recovery", "shed",
-            "tenant_throttle"} <= set(SPAN_CATEGORIES)
+            "checkpoint", "recovery", "shed", "tenant_throttle", "dispatch",
+            "sync"} <= set(SPAN_CATEGORIES)
 
 
 # ------------------------------------------- end-to-end open-loop contract
@@ -318,27 +340,8 @@ def test_open_loop_obs_windows_cover_trace_duration():
 # ------------------------------------------------------------- roofline
 
 
-def test_measured_kernel_table_from_dispatch_stats():
-    from repro.roofline.analysis import measured_kernel_table
-
-    stats = {
-        "_flush_impl": {"count": 4, "wall_s": 2.0, "bytes": 8_190_000_000},
-        "_insert_impl": {"count": 100, "wall_s": 0.1, "bytes": 1_000_000},
-    }
-    rows = measured_kernel_table(stats, device_kind="TPU v5 lite")
-    assert [r["kernel"] for r in rows] == ["_flush_impl", "_insert_impl"]
-    assert rows[0]["achieved_gb_s"] == pytest.approx(4.095)
-    assert rows[0]["peak_frac"] == pytest.approx(0.005)
-    assert rows[1]["count"] == 100
-    zero = measured_kernel_table({"k": {"count": 1, "wall_s": 0.0,
-                                        "bytes": 10}},
-                                 device_kind="TPU v5 lite")
-    assert zero[0]["achieved_gb_s"] == 0.0
-
-
 def test_chip_peaks_keyed_by_device_kind():
     from repro.roofline import hardware as hw
-    from repro.roofline.analysis import measured_kernel_table
 
     v5e = hw.peaks("TPU v5 lite")
     assert (v5e.flops_bf16, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
@@ -346,5 +349,3 @@ def test_chip_peaks_keyed_by_device_kind():
     assert "TPU v5e" in v5e.source
     with pytest.raises(KeyError, match="cpu"):
         hw.peaks("cpu")
-    with pytest.raises(KeyError):
-        measured_kernel_table({}, device_kind="TPU v4")

@@ -673,8 +673,7 @@ class DeviceNBTreeEngine(StorageEngine):
         elif kind is OpKind.QUERY:
             real = sl.stop - sl.start
             pres, vals = idx.query_batch(keys)
-            pres = idx._fetch("found", pres)[:real]
-            vals = idx._fetch("values", vals)[:real]
+            pres, vals = pres[:real], vals[:real]
             res.found[sl] = pres
             res.values[sl] = np.where(pres, vals.astype(np.int64), -1)
         else:

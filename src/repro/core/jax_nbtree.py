@@ -371,9 +371,10 @@ def _query_batch_impl(pivots, nchild, children, run_keys, run_vals, run_count,
     node = jnp.zeros(B, jnp.int32)
     found = jnp.zeros(B, bool)
     out = jnp.full(B, -1, jnp.int32)
-    # Bloom-effectiveness tallies (paper Sec. 5.2), reduced on device so the
-    # fused call stays one round trip: probes issued, negatives that skipped
-    # a run search, and positives whose search then missed (false positives).
+    # Bloom-effectiveness tallies (paper Sec. 5.2), reduced on device and
+    # packed with the results so the call stays one round trip: probes
+    # issued, negatives that skipped a run search, and positives whose
+    # search then missed (false positives).
     n_probe = jnp.int32(0)
     n_neg = jnp.int32(0)
     n_fp = jnp.int32(0)
@@ -415,7 +416,10 @@ def _query_batch_impl(pivots, nchild, children, run_keys, run_vals, run_count,
         prev = node
         node = jnp.where(nchild[node] > 0, child, node)
     present = found & (out != TOMBSTONE32)
-    return present, out, n_probe, n_neg, n_fp
+    # one int32 vector, so the caller reads everything in one transfer:
+    # present (0/1) | out | n_probe, n_neg, n_fp
+    return jnp.concatenate([present.astype(jnp.int32), out,
+                            jnp.stack([n_probe, n_neg, n_fp])])
 
 
 @functools.partial(
@@ -551,9 +555,9 @@ class NBTreeIndex:
         """The sync counterpart of :meth:`_dispatch`: every device->host
         wait or read of the fused path is ``read(x)`` here (``np.asarray``
         by default, ``jax.block_until_ready`` for a bare wait).  ``what``
-        names it (``ack``, ``found``, ``values``, ``bloom_tallies``,
-        ``flush_counts``, ``split_out``, ``range_*``) on the
-        ``nbtree.sync`` span; ``sync_count`` counts every call."""
+        names it (``ack``, ``query``, ``flush_counts``, ``split_out``,
+        ``range_*``) on the ``nbtree.sync`` span; ``sync_count`` counts
+        every call."""
         self.sync_count += 1
         if self._tracer is None:
             return read(x)
@@ -628,24 +632,27 @@ class NBTreeIndex:
         self.insert_batch(keys, jnp.full(keys.shape, TOMBSTONE32, jnp.int32))
 
     def query_batch(self, keys):
-        """(present: bool (B,), vals: int32 (B,)) — one fused device call.
+        """Host numpy ``(present: bool (B,), vals: int32 (B,))`` — one
+        fused device call and one device->host transfer (``query``).
 
         Bloom-effectiveness tallies for the batch (probes / negative skips /
-        false positives, reduced on device) accumulate into
-        ``bloom_probes`` / ``bloom_negative_skips`` /
-        ``bloom_false_positives`` — the paper Sec. 5.2 attribution counters
-        surfaced through ``EngineStats``.
+        false positives, reduced on device, read back in the same transfer)
+        accumulate into the host ints ``bloom_probes`` /
+        ``bloom_negative_skips`` / ``bloom_false_positives`` — the paper
+        Sec. 5.2 attribution counters surfaced through ``EngineStats``.
         """
         q = jnp.asarray(keys, jnp.uint32)
-        present, out, n_probe, n_neg, n_fp = self._dispatch(
+        B = int(q.shape[0])
+        packed = self._fetch("query", self._dispatch(
             _query_batch_impl, self.pivots, self.nchild, self.children,
             self.run_keys, self.run_vals, self.run_count, self.bloom, q,
             f=self.f, levels=self.max_levels, run_cap=self.run_cap,
-            nbits=self.nbits, h=self.h, steps=self._steps)
-        self.bloom_probes += int(self._fetch("bloom_tallies", n_probe))
-        self.bloom_negative_skips += int(self._fetch("bloom_tallies", n_neg))
-        self.bloom_false_positives += int(self._fetch("bloom_tallies", n_fp))
-        return present, out
+            nbits=self.nbits, h=self.h, steps=self._steps))
+        n_probe, n_neg, n_fp = packed[2 * B:].tolist()
+        self.bloom_probes += n_probe
+        self.bloom_negative_skips += n_neg
+        self.bloom_false_positives += n_fp
+        return packed[:B] != 0, packed[B:2 * B]
 
     def range_query_batch(self, lo, hi, max_results: int = 256):
         """Batched inclusive range scan [lo_b, hi_b] — one fused device call.

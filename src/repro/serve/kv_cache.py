@@ -76,8 +76,7 @@ class PagedKVCache:
         if n_blocks:
             keys = pack_key(seq_id, np.arange(n_blocks))
             present, pages = self.index.query_batch(keys)
-            pages = np.asarray(pages)[np.asarray(present)]
-            self.free.extend(int(p) for p in pages)
+            self.free.extend(int(p) for p in pages[present])
             self.index.delete_batch(keys)
         del self.seq_len[seq_id]
 
@@ -107,8 +106,7 @@ class PagedKVCache:
         slots = positions % self.S
         keys = pack_key(seq_ids, blocks)
         present, pages = self.index.query_batch(keys)
-        assert bool(np.asarray(present).all()), "write to unallocated block"
-        pages = np.asarray(pages)
+        assert present.all(), "write to unallocated block"
         # batched scatter; advanced indices (pages, slots) broadcast to (B,)
         # and land in front, so the update value is exactly k/v (B, KVH, D).
         self.k_pages = self.k_pages.at[layer, :, pages, slots].set(k)

@@ -5,11 +5,11 @@
   ``nbtree.sync`` lands in the trace's host plane inside an ``nbtree.run``
   or ``nbtree.unit``, and their counts equal the index's
   ``dispatch_count`` / ``sync_count`` deltas.
-* **Sync budget** — a read run makes 5 device->host syncs (three Bloom
-  tallies, found, values), an insert or delete run 1 (the ack), a range
-  run 4, a flush unit 1 and a leaf split unit 1: the regression guard for
-  the blocking reads ROADMAP speed item 2 wants gone, in the manner of
-  ``test_flush_unit_is_one_dispatch``.
+* **Sync budget** — a read run makes 1 device->host sync (one packed
+  ``query`` transfer: found, values and the Bloom tallies), an insert or
+  delete run 1 (the ack), a range run 4, a flush unit 1 and a leaf split
+  unit 1: the regression guard for the blocking reads ROADMAP speed item 2
+  wants gone, in the manner of ``test_flush_unit_is_one_dispatch``.
 * **Detached** — after ``attach_tracer(None)`` an engine (single or
   sharded) calls no tracer method and records nothing.
 """
@@ -101,8 +101,8 @@ def test_profiler_nests_dispatch_and_sync_in_run_or_unit(tmp_path):
     units = [st["kind"] for n, _, _, st in events if n == "nbtree.unit"]
     assert "flush" in units and "split_leaf" in units
     whats = {st["what"] for n, _, _, st in events if n == "nbtree.sync"}
-    assert {"ack", "found", "values", "bloom_tallies", "flush_counts",
-            "split_out", "range_keys"} <= whats
+    assert {"ack", "query", "flush_counts", "split_out",
+            "range_keys"} <= whats
     # the ring buffer holds the same spans
     assert len(tr.spans("sync")) == syncs and tr.dropped_events == 0
     assert sum(e["name"] == "nbtree.dispatch"
@@ -127,7 +127,7 @@ def test_sync_budget_per_run_and_unit():
         k = rng.integers(1, 2**31, INSERTS, dtype=np.uint64)
         lo = rng.integers(1, 2**31 - 2**20, RANGES, dtype=np.uint64)
         assert syncs(OpBatch.inserts(k, np.arange(INSERTS))) == 1
-        assert syncs(OpBatch.queries(k[:READS])) == 5
+        assert syncs(OpBatch.queries(k[:READS])) == 1
         assert syncs(OpBatch.deletes(k[:DELETES])) == 1
         assert syncs(OpBatch.ranges(lo, lo + 2**20)) == 4
         while idx._pending:

@@ -1,9 +1,13 @@
 """Device-tier NB-tree (core/jax_nbtree): behaviour, invariants, ref-parity."""
+import bisect
+
 import numpy as np
 import pytest
 
+from repro.core.engine_api import _pad_pow2
 from repro.core.jax_nbtree import NBTreeIndex
 from repro.core.refimpl import NBTree as RefNBTree
+from repro.kernels.ref import bloom_hash_ref
 
 
 def _keys(rng, n):
@@ -101,3 +105,68 @@ def test_grow_tables():
     assert idx.max_nodes > 8
     p, _ = idx.query_batch(keys[:512])
     assert np.array(p).all()
+
+
+def _bloom_walk(idx, q):
+    """(probes, negative skips, false positives) of a point-read batch,
+    counted by walking the host tree in numpy: one probe per distinct node
+    with a non-empty run on each query's root-to-leaf path, until a run
+    holds the key."""
+    bloom = np.asarray(idx.bloom)
+    run_keys = np.asarray(idx.run_keys)
+    run_count = np.asarray(idx.run_count)
+    pos = np.asarray(bloom_hash_ref(q, idx.h, idx.nbits))      # (h, B)
+    probes = neg = fp = 0
+    for b, k in enumerate(q):
+        node = idx.root
+        while True:
+            cnt = run_count[node.nid]
+            if cnt:
+                probes += 1
+                p = pos[:, b]
+                bits = (bloom[node.nid, p // 32] >> (p % 32).astype(np.uint32)) & 1
+                if not bits.all():
+                    neg += 1
+                elif k in run_keys[node.nid, :cnt]:
+                    break
+                else:
+                    fp += 1
+            if node.is_leaf:
+                break
+            node = node.children[bisect.bisect_right(node.skeys, int(k))]
+    return probes, neg, fp
+
+
+def test_query_batch_is_one_sync_with_exact_bloom_tallies():
+    """A point-read batch is one device->host transfer returning host
+    arrays, and its Bloom tallies equal an independent count."""
+    rng = np.random.default_rng(11)
+    keys = _keys(rng, 2048)
+    idx = NBTreeIndex(f=4, sigma=64, max_nodes=512)
+    for i in range(0, len(keys), 64):       # runs left partly full
+        idx.insert_batch(keys[i:i + 64], np.arange(i, i + 64, dtype=np.int32))
+        idx.maintain(1)
+    dead = keys[-64:]
+    idx.delete_batch(dead)                  # tombstones in the root run
+    assert idx.height >= 2
+    absent = rng.integers(2**31, 2**32 - 2, 16).astype(np.uint32)
+    want = {int(k): i for i, k in enumerate(keys[:-64])}
+    mixed = np.concatenate([keys[:24], keys[900:916], absent, dead[:8]])
+    padded = _pad_pow2(np.concatenate([keys[1000:1024], absent[:8],
+                                       dead[8:13]]))
+    assert len(padded) == len(mixed) == 64 and (padded[36:] == dead[12]).all()
+    for q in (mixed, padded):
+        s0 = idx.sync_count
+        t0 = (idx.bloom_probes, idx.bloom_negative_skips,
+              idx.bloom_false_positives)
+        present, vals = idx.query_batch(q)
+        assert idx.sync_count - s0 == 1
+        assert isinstance(present, np.ndarray) and present.dtype == bool
+        assert isinstance(vals, np.ndarray) and vals.dtype == np.int32
+        assert present.shape == vals.shape == q.shape
+        assert present.tolist() == [int(k) in want for k in q]
+        assert vals[present].tolist() == [want[int(k)] for k in q[present]]
+        got = (idx.bloom_probes - t0[0], idx.bloom_negative_skips - t0[1],
+               idx.bloom_false_positives - t0[2])
+        assert got == _bloom_walk(idx, q)
+        assert got[0] > len(q) and got[1] > 0

@@ -243,6 +243,10 @@ class EngineStats:
     #: (``NBTreeIndex.sync_count``; sharded ensembles sum).  Sim tiers
     #: report 0.
     device_syncs: int = 0
+    #: maintenance units run inside ``apply`` because the root had no room
+    #: for the next chunk of a write run (``NBTreeIndex.backpressure_units``;
+    #: sharded ensembles sum).  Sim tiers report 0.
+    backpressure_units: int = 0
     #: highest WAL commit LSN applied to this engine (0 = never ran under a
     #: durable frontend).  Written by the durable ingest path via
     #: :meth:`StorageEngine.note_applied`; the recovery invariant is that a
@@ -594,7 +598,8 @@ class DeviceNBTreeEngine(StorageEngine):
     ``nbtree.apply`` span (``ops``, ``seq``: this engine's commit number)
     holding one ``nbtree.run`` span per same-kind run (``kind``, ``n``,
     ``padded``), and the index adds its ``nbtree.unit`` /
-    ``nbtree.dispatch`` / ``nbtree.sync`` spans (``core/jax_nbtree.py``).
+    ``nbtree.backpressure`` / ``nbtree.dispatch`` / ``nbtree.sync`` spans
+    (``core/jax_nbtree.py``).
     """
 
     name = "jax-nbtree"
@@ -782,6 +787,7 @@ class DeviceNBTreeEngine(StorageEngine):
             maintain_unit_p100_s=mu.max if mu.count else 0.0,
             device_dispatches=self.idx.dispatch_count,
             device_syncs=self.idx.sync_count,
+            backpressure_units=self.idx.backpressure_units,
             applied_lsn=self.applied_lsn)
 
 

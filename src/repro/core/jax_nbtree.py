@@ -44,9 +44,10 @@ goes through the ``_device_call`` funnel (``_dispatch``), and every
 device->host wait or read of the fused path through ``_fetch``, so dispatch
 and sync budgets are observable (per-instance ``dispatch_count`` and
 ``sync_count``) and regression-tested.  With a
-:class:`repro.obs.trace.Tracer` attached, each maintenance unit, dispatch
-and sync is also a span (``nbtree.unit`` / ``nbtree.dispatch`` /
-``nbtree.sync``) in the ring buffer and in a running profiler's trace.
+:class:`repro.obs.trace.Tracer` attached, each maintenance unit, forced
+maintenance call, dispatch and sync is also a span (``nbtree.unit`` /
+``nbtree.backpressure`` / ``nbtree.dispatch`` / ``nbtree.sync``) in the ring
+buffer and in a running profiler's trace.
 The pre-fusion eager path is kept under ``fused=False`` as the
 differential-testing and benchmarking baseline
 (``benchmarks/bench_ingest_device.py`` measures the before/after); its own
@@ -120,7 +121,7 @@ def _round_up(x: int, m: int) -> int:
 class _HostNode:
     """Control-plane view of an s-node (structure only, no key data)."""
 
-    __slots__ = ("nid", "skeys", "children", "count", "parent")
+    __slots__ = ("nid", "skeys", "children", "count", "parent", "requeued")
 
     def __init__(self, nid: int, parent=None):
         self.nid = nid
@@ -128,6 +129,7 @@ class _HostNode:
         self.children: list[_HostNode] = []
         self.count = 0           # live pairs in the device run row
         self.parent: _HostNode | None = parent
+        self.requeued = False    # queued by the split that made it
 
     @property
     def is_leaf(self):
@@ -291,17 +293,22 @@ def _flush_impl(run_keys, run_vals, run_count, bloom, nid, child_ids, piv,
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
-                   static_argnames=("has_key", "run_cap", "nbits", "h"))
+                   static_argnames=("has_key", "run_cap", "nbits", "h",
+                                    "compact"))
 def _split_impl(run_keys, run_vals, run_count, bloom, nid, left_id, right_id,
                 count, at_key, *, has_key: bool, run_cap: int, nbits: int,
-                h: int):
+                h: int, compact: bool = False):
     """One-dispatch run split: windows, counts and filters for both halves.
 
-    Returns the updated tables plus ``[k_m, cut]`` (uint32) — the split key
-    for the host pivot structure and the left-half length.
+    ``compact`` first resolves the run's duplicates and deletes as a leaf
+    flush does (the root leaf's run has never been compacted).  Returns the
+    updated tables plus ``[k_m, cut, count]`` (uint32) — the split key for
+    the host pivot structure, the left-half length and the pairs split.
     """
     row_k = run_keys[nid]
     row_v = run_vals[nid]
+    if compact:
+        row_k, row_v, count = _compact_rows(row_k, row_v, run_cap)
     if has_key:
         k_m = at_key
         cut = jnp.minimum(
@@ -327,7 +334,7 @@ def _split_impl(run_keys, run_vals, run_count, bloom, nid, left_id, right_id,
     bloom = bloom.at[ids].set(
         jnp.stack([bloom_build_ref(halves_k[i], nbits, h) for i in range(2)]))
     return (run_keys, run_vals, run_count, bloom,
-            jnp.stack([k_m, cut.astype(jnp.uint32)]))
+            jnp.stack([k_m, cut.astype(jnp.uint32), count.astype(jnp.uint32)]))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
@@ -520,6 +527,9 @@ class NBTreeIndex:
         self._pending_n: Counter = Counter()
         self.n_items = 0
         self.units_done = 0   # cumulative flush/split work units executed
+        #: units ``insert_batch`` ran to make room at the root (surfaced as
+        #: ``EngineStats.backpressure_units``).
+        self.backpressure_units = 0
         # Bloom effectiveness (paper Sec. 5.2); see query_batch.
         self.bloom_probes = 0
         self.bloom_negative_skips = 0
@@ -591,11 +601,27 @@ class NBTreeIndex:
         if self.root.count + n > self.run_cap or n > self.sigma:
             for i in range(0, n, self.sigma):
                 while self.root.count + self.sigma > self.run_cap:
-                    if self.maintain(4) == 0 and self.root.count + self.sigma > self.run_cap:
+                    if (self._backpressure(i // self.sigma) == 0 and
+                            self.root.count + self.sigma > self.run_cap):
                         break  # tree fully maintained; capacity guaranteed
                 self._insert_chunk(keys[i:i + self.sigma], vals[i:i + self.sigma])
             return
         self._insert_chunk(keys, vals)
+
+    def _backpressure(self, chunk: int) -> int:
+        """One ``maintain(4)`` forced by a root without room for chunk
+        ``chunk`` of a batch: its units count in ``backpressure_units``,
+        and with a tracer attached it is an ``nbtree.backpressure`` span
+        (``chunk``; ``units``: the call's budget)."""
+        u0 = self.units_done
+        if self._tracer is None:
+            pending = self.maintain(4)
+        else:
+            with self._tracer.span("cascade", "nbtree.backpressure",
+                                   chunk=chunk, units=4):
+                pending = self.maintain(4)
+        self.backpressure_units += self.units_done - u0
+        return pending
 
     def _insert_chunk(self, keys, vals) -> None:
         n = int(keys.shape[0])
@@ -723,14 +749,33 @@ class NBTreeIndex:
             node = self._dequeue()
             if node.count <= self.sigma:
                 continue
+            node = self._make_room(node)
+            requeued, node.requeued = node.requeued, False
             if self._tracer is None:
                 units += self._handle_full(node)
             else:
                 with self._tracer.span("flush_unit", "nbtree.unit",
                                        kind=self._unit_kind(node),
-                                       pairs=node.count):
+                                       pairs=node.count, requeued=requeued):
                     units += self._handle_full(node)
         return len(self._pending)
+
+    def _make_room(self, node: _HostNode) -> _HostNode:
+        """The node whose unit runs now.  A flush moves at most sigma pairs
+        into a child, so it may target only children with ``count + sigma
+        <= run_cap``; where ``node`` has a child without that room, the
+        child's unit runs first (a flush takes sigma out of it, a split
+        halves it) and ``node`` goes back to the front of the queue, and so
+        on down.  This keeps every flush inside ``run_cap`` for any key
+        order (DESIGN.md §8)."""
+        while not node.is_leaf:
+            full = [c for c in node.children
+                    if c.count + self.sigma > self.run_cap]
+            if not full:
+                break
+            self._enqueue(node, front=True)
+            node = max(full, key=lambda c: c.count)
+        return node
 
     def _unit_kind(self, node: _HostNode) -> str:
         """What :meth:`_handle_full` will do to ``node``: ``flush`` an
@@ -877,7 +922,14 @@ class NBTreeIndex:
     def _split_root_leaf(self) -> None:
         """First split: the root leaf becomes a root with two leaf children."""
         left, right = self._alloc(self.root), self._alloc(self.root)
-        k_m = self._split_run(self.root, left, right)
+        # the root leaf's run is the one leaf run no flush has compacted;
+        # compacting it keeps every leaf run free of duplicates, so a leaf
+        # split always halves its run (DESIGN.md §8)
+        k_m = self._split_run(self.root, left, right, compact=True)
+        if left.count + right.count == 0:
+            # every pair was a stale copy or a delete: the tree is empty
+            self._clear_run(self.root)
+            return
         self.root.skeys = [k_m]
         self.root.children = [left, right]
         self._sync_structure(self.root)
@@ -913,6 +965,9 @@ class NBTreeIndex:
         old.children = [left, right]
         left.parent = right.parent = old
         self._sync_structure(old)
+        # the query descent visits max_levels + 1 nodes: a taller tree
+        # would answer from a prefix of the path.
+        assert self.height <= self.max_levels, "tree taller than max_levels"
 
     def _split_structure(self, node, left, right) -> int:
         """Split node's run (and pivots/children for internal nodes)."""
@@ -936,7 +991,15 @@ class NBTreeIndex:
         node.count = 0
         return k_m
 
-    def _split_run(self, node, left, right, at_key: int | None = None) -> int:
+    def _split_run(self, node, left, right, at_key: int | None = None,
+                   compact: bool = False) -> int:
+        """Split ``node``'s run into ``left`` and ``right`` at ``at_key``
+        (its median key where None; ``compact``: the run's duplicates and
+        deletes resolved first).  An internal half holding more than sigma
+        pairs goes to the front of the queue, as :meth:`_handle_full` does
+        with the largest child after a flush: it holds pairs in transit,
+        and the split retired the node's own queue entry.  A leaf half owes
+        no flush, and splitting it again only spends rows."""
         if self._fused:
             has_key = at_key is not None
             (self.run_keys, self.run_vals, self.run_count, self.bloom,
@@ -946,15 +1009,28 @@ class NBTreeIndex:
                 jnp.int32(right.nid), jnp.int32(node.count),
                 jnp.uint32(at_key if has_key else 0),
                 has_key=has_key, run_cap=self.run_cap, nbits=self.nbits,
-                h=self.h)
+                h=self.h, compact=compact)
             out = self._fetch("split_out", out)  # the split's one sync
-            k_m, cut = int(out[0]), int(out[1])
-            left.count, right.count = cut, node.count - cut
-            return k_m
+            k_m, cut, count = (int(x) for x in out)
+            left.count, right.count = cut, count - cut
+        else:
+            k_m = self._split_run_eager(node, left, right, at_key, compact)
+        for half in (right, left):
+            if not half.is_leaf and half.count > self.sigma:
+                half.requeued = True
+                self._enqueue(half, front=True)
+        return k_m
+
+    def _split_run_eager(self, node, left, right, at_key, compact) -> int:
         nid = node.nid
         row_k, row_v = self.run_keys[nid], self.run_vals[nid]
+        count = node.count
+        if compact:
+            row_k, row_v, live = self._dispatch(_compact_tombstones, row_k,
+                                                row_v, self.run_cap)
+            count = int(live)
         if at_key is None:
-            mid = node.count // 2
+            mid = count // 2
             k_m = int(np.asarray(row_k[mid]))
             cut = int(np.asarray(self._dispatch(
                 jnp.searchsorted, row_k, jnp.uint32(k_m), side="left")))
@@ -962,8 +1038,8 @@ class NBTreeIndex:
             k_m = int(at_key)
             cut = int(np.asarray(self._dispatch(
                 jnp.searchsorted, row_k, jnp.uint32(k_m), side="left")))
-            cut = min(cut, node.count)
-        for dst, lo, ln in ((left, 0, cut), (right, cut, node.count - cut)):
+            cut = min(cut, count)
+        for dst, lo, ln in ((left, 0, cut), (right, cut, count - cut)):
             dk, dv = self._dispatch(_window, row_k, row_v, jnp.int32(lo),
                                   jnp.int32(ln), self.run_cap)
             self.run_keys = self._dispatch(_write_row, self.run_keys, dst.nid, dk)
@@ -1018,10 +1094,14 @@ class NBTreeIndex:
 
     # ------------------------------------------------------------- invariants
     def check_invariants(self) -> None:
-        assert not self._pending, "drain() before checking invariants"
+        """Structure, key order and run bounds of the whole tree; they hold
+        between any two units, so work may still be pending."""
         run_keys = np.asarray(self.run_keys)
+        run_count = np.asarray(self.run_count)
 
         def rec(node, lo, hi_excl, depth, depths):
+            assert node.count <= self.run_cap, "run over run_cap"
+            assert run_count[node.nid] == node.count, "device count differs"
             ks = run_keys[node.nid][: node.count]
             if len(ks):
                 assert np.all(ks[:-1] <= ks[1:]), "run not sorted"

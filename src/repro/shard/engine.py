@@ -78,7 +78,7 @@ class ShardedEngine(StorageEngine):
         # (io_s, seeks, rd, wr, bloom probes / skips / false positives,
         #  maintain units, maintain wall seconds, device dispatches,
         #  device syncs)
-        self._retired = [0.0, 0, 0, 0, 0, 0, 0, 0, 0.0, 0, 0]
+        self._retired = [0.0, 0, 0, 0, 0, 0, 0, 0, 0.0, 0, 0, 0]
         self._tracer = None
         if partition == "hash":
             self.partitioner = HashPartitioner(shards)
@@ -280,6 +280,7 @@ class ShardedEngine(StorageEngine):
         self._retired[8] += st.maintain_wall_s
         self._retired[9] += st.device_dispatches
         self._retired[10] += st.device_syncs
+        self._retired[11] += st.backpressure_units
         lineage_s = self._inherited_s[sid] + eng.io_time_s()
         left = rk < np.uint64(q)
         a, b = self._make_shard(), self._make_shard()
@@ -390,4 +391,6 @@ class ShardedEngine(StorageEngine):
             device_dispatches=self._retired[9] + sum(s.device_dispatches
                                                      for s in per),
             device_syncs=self._retired[10] + sum(s.device_syncs for s in per),
+            backpressure_units=(self._retired[11]
+                                + sum(s.backpressure_units for s in per)),
             applied_lsn=self.applied_lsn)

@@ -12,6 +12,9 @@
   wants gone, in the manner of ``test_flush_unit_is_one_dispatch``.
 * **Detached** — after ``attach_tracer(None)`` an engine (single or
   sharded) calls no tracer method and records nothing.
+* **Backpressure** — ``backpressure_units`` counts the units a write run
+  forces inside ``apply`` (none for commits of at most sigma), each inside
+  an ``nbtree.backpressure`` span nested in ``nbtree.apply``.
 """
 import glob
 
@@ -191,3 +194,60 @@ def test_detached_engine_records_nothing(name):
     assert spy.calls == calls and len(spy) == n
     st = eng.stats()
     assert st.device_syncs == sum(e.idx.sync_count for e in devices) > 0
+
+
+def test_backpressure_counter_zero_for_commits_up_to_sigma():
+    """Commits of at most sigma ops with maintain(1) after each never find
+    the root without room: no unit runs inside apply."""
+    rng = np.random.default_rng(17)
+    eng = make_engine("jax-nbtree", **TINY)
+    for _ in range(40):
+        _round(eng, rng, mixed=False)
+    assert eng.idx.units_done > 10
+    assert eng.idx.backpressure_units == eng.stats().backpressure_units == 0
+
+
+def test_backpressure_counter_and_span_inside_apply(tmp_path):
+    """A commit of 4,000 ascending inserts (padded to 4,096: 64 chunks of
+    sigma) runs its forced maintenance inside apply:
+    ``backpressure_units`` counts exactly the units run there, each inside an ``nbtree.backpressure`` span that
+    nests in ``nbtree.apply``; split halves put back on the queue show as
+    units with ``requeued``."""
+    import jax
+
+    eng = make_engine("jax-nbtree", **TINY)
+    idx = eng.idx
+    tr = Tracer()
+    eng.attach_tracer(tr)
+    keys = np.arange(1, 8001, dtype=np.uint64) * 5
+    eng.apply(OpBatch.inserts(keys[:4000], np.arange(4000)))   # compiles
+    eng.drain()
+    b0, u0 = idx.backpressure_units, idx.units_done
+    n_bp = len(tr.spans("cascade"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.apply(OpBatch.inserts(keys[4000:], np.arange(4000)))
+    finally:
+        jax.profiler.stop_trace()
+    forced = idx.backpressure_units - b0
+    assert forced == idx.units_done - u0 > 0
+    assert eng.stats().backpressure_units == idx.backpressure_units
+
+    (events,) = _host_events(str(tmp_path)).values()
+    (apply_,) = [(s, e) for n, s, e, _ in events if n == "nbtree.apply"]
+    bp = [(s, e, st) for n, s, e, st in events if n == "nbtree.backpressure"]
+    units = [(s, e, st) for n, s, e, st in events if n == "nbtree.unit"]
+    assert bp and all(apply_[0] <= s and e <= apply_[1] for s, e, _ in bp)
+    assert all(st["units"] == 4 and 0 <= st["chunk"] < 64 for *_, st in bp)
+    assert len(units) == forced
+    assert all(any(bs <= s and e <= be for bs, be, _ in bp)
+               for s, e, _ in units)
+    assert len(tr.spans("cascade")) - n_bp == len(bp)
+    eng.drain()
+    eng.attach_tracer(None)
+    requeued = [e["args"] for e in tr.spans("flush_unit")
+                if e["args"]["requeued"]]
+    assert requeued and all(a["pairs"] > idx.sigma for a in requeued)

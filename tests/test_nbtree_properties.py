@@ -89,7 +89,11 @@ def test_range_query_matches_dict_model(ops, f, sigma, ranges):
        seed=st.integers(min_value=0, max_value=2**16))
 def test_invariants_under_bulk_load(n, seed):
     rng = np.random.default_rng(seed)
-    keys = rng.choice(np.arange(1, 1 << 40, dtype=np.uint64), n, replace=False)
+    # draw with room for collisions, keep first occurrences in draw order
+    draw = rng.integers(1, 1 << 40, n + 64, dtype=np.uint64)
+    _, first = np.unique(draw, return_index=True)
+    keys = draw[np.sort(first)][:n]
+    assert len(keys) == n
     nb = NBTree(f=3, sigma=64)
     for i, k in enumerate(keys):
         nb.insert(k, i)

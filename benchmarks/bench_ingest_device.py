@@ -52,9 +52,8 @@ QUICK_KWARGS = dict(n_batches=48, warmup=24, batch=256, sigma=512)
 def _precompile_fused(idx: NBTreeIndex) -> None:
     """Compile every fused maintenance variant against the live table shapes.
 
-    The fused impls are shape-specialized jits keyed on (child count, leaf
-    level, split mode); variants appear as the tree grows — an internal
-    node's first 4th child can arrive mid-measurement and would charge its
+    The fused impls are shape-specialized jits (the split keyed on its
+    mode); a variant first met mid-measurement would charge its
     multi-second first compile to one unlucky unit.  Warming them on dummy
     tables of identical shape keeps the measured window compile-free.  The
     eager path needs no equivalent: its helpers are per-table-shape only
@@ -66,14 +65,12 @@ def _precompile_fused(idx: NBTreeIndex) -> None:
                      jnp.zeros_like(idx.run_vals),
                      jnp.zeros_like(idx.run_count),
                      jnp.zeros_like(idx.bloom))
-    for nc in range(2, idx.f + 1):
-        for leaf in (True, False):
-            jax.block_until_ready(jnb._flush_impl(
-                *dummy(), jnp.int32(0), jnp.zeros(nc, jnp.int32),
-                jnp.zeros(max(nc - 1, 1), jnp.uint32)[: nc - 1],
-                jnp.int32(idx.sigma + 1), nc=nc, leaf=leaf, sigma=idx.sigma,
-                sigma_pad=idx.sigma_pad, run_cap=idx.run_cap,
-                nbits=idx.nbits, h=idx.h, interpret=ops._interpret()))
+    jax.block_until_ready(jnb._flush_impl(
+        *dummy(), jnp.zeros_like(idx.pivots), jnp.zeros_like(idx.children),
+        jnp.zeros_like(idx.nchild), jnp.int32(-1),
+        jnp.zeros_like(idx._chain_log), sigma=idx.sigma,
+        sigma_pad=idx.sigma_pad, run_cap=idx.run_cap, nbits=idx.nbits,
+        h=idx.h, interpret=ops._interpret()))
     for has_key in (False, True):
         jax.block_until_ready(jnb._split_impl(
             *dummy(), jnp.int32(0), jnp.int32(1), jnp.int32(2),
@@ -97,9 +94,9 @@ def _ingest(fused: bool, *, n_batches: int, warmup: int, batch: int,
     units = {"flush": 0, "split": 0}
     orig_handle = idx._handle_full
 
-    def counted(node):
+    def counted(node, budget=1):
         units["split" if node.is_leaf else "flush"] += 1
-        return orig_handle(node)
+        return orig_handle(node, budget)
 
     idx._handle_full = counted
 

@@ -253,16 +253,15 @@ def impls_hold_kernels(idx) -> dict:
     spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
     tables = [spec(t) for t in (idx.run_keys, idx.run_vals, idx.run_count,
                                 idx.bloom)]
-    scalar = jax.ShapeDtypeStruct((), jnp.int32)
     common = dict(run_cap=idx.run_cap, nbits=idx.nbits, h=idx.h,
                   interpret=False)
     insert = J._insert_impl.lower(
         *tables, jax.ShapeDtypeStruct((idx.sigma,), jnp.uint32),
         jax.ShapeDtypeStruct((idx.sigma,), jnp.int32), **common)
     flush = J._flush_impl.lower(
-        *tables, scalar, jax.ShapeDtypeStruct((idx.f,), jnp.int32),
-        jax.ShapeDtypeStruct((idx.f - 1,), jnp.uint32), scalar, nc=idx.f,
-        leaf=True, sigma=idx.sigma, sigma_pad=idx.sigma_pad, **common)
+        *tables, *(spec(t) for t in (idx.pivots, idx.children, idx.nchild)),
+        jax.ShapeDtypeStruct((), jnp.int32), spec(idx._chain_log),
+        sigma=idx.sigma, sigma_pad=idx.sigma_pad, **common)
     return {name: "tpu_custom_call" in low.compile().as_text()
             for name, low in (("_insert_impl", insert), ("_flush_impl", flush))}
 

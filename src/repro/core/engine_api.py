@@ -247,6 +247,11 @@ class EngineStats:
     #: for the next chunk of a write run (``NBTreeIndex.backpressure_units``;
     #: sharded ensembles sum).  Sim tiers report 0.
     backpressure_units: int = 0
+    #: flush units that ran as a later link of a chain of flush programs,
+    #: without a device->host sync of their own
+    #: (``NBTreeIndex.chained_units``; sharded ensembles sum).  Sim tiers
+    #: report 0.
+    chained_units: int = 0
     #: highest WAL commit LSN applied to this engine (0 = never ran under a
     #: durable frontend).  Written by the durable ingest path via
     #: :meth:`StorageEngine.note_applied`; the recovery invariant is that a
@@ -598,8 +603,8 @@ class DeviceNBTreeEngine(StorageEngine):
     ``nbtree.apply`` span (``ops``, ``seq``: this engine's commit number)
     holding one ``nbtree.run`` span per same-kind run (``kind``, ``n``,
     ``padded``), and the index adds its ``nbtree.unit`` /
-    ``nbtree.backpressure`` / ``nbtree.dispatch`` / ``nbtree.sync`` spans
-    (``core/jax_nbtree.py``).
+    ``nbtree.backpressure`` / ``nbtree.chain`` / ``nbtree.dispatch`` /
+    ``nbtree.sync`` spans (``core/jax_nbtree.py``).
     """
 
     name = "jax-nbtree"
@@ -788,6 +793,7 @@ class DeviceNBTreeEngine(StorageEngine):
             device_dispatches=self.idx.dispatch_count,
             device_syncs=self.idx.sync_count,
             backpressure_units=self.idx.backpressure_units,
+            chained_units=self.idx.chained_units,
             applied_lsn=self.applied_lsn)
 
 

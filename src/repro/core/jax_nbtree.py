@@ -25,9 +25,10 @@ device dispatch:
                         Bloom update (OR only the batch's bits: O(batch),
                         not O(run_cap), and bit-identical to a rebuild —
                         see ``kernels.ref.bloom_update_ref``),
-  * ``_flush_impl``   — the whole emptying cascade step for one node:
-                        duplicate-safe cut, pivot partition, batched
-                        merge-path merge into all <= f children
+  * ``_flush_impl``   — the whole emptying cascade step for the node a
+                        device cursor names, and the next cursor by the
+                        host's rule: duplicate-safe cut, pivot partition,
+                        batched merge-path merge into all <= f children
                         (``merge_sorted_batch``, a single 2-d-grid kernel
                         launch), fused tombstone compaction, parent-run
                         compaction, and child/parent Bloom rebuilds, with
@@ -37,17 +38,20 @@ device dispatch:
                         run split (+ filters), row clear, structure mirror,
                         and capacity doubling, one dispatch each.
 
-Host control metadata (node id, child ids, pivots) is routed in as scalars
-and tiny arrays; the only device->host traffic per flush is the returned
-(<= f+1)-element count vector.  Every device computation the index launches
-goes through the ``_device_call`` funnel (``_dispatch``), and every
+Host control metadata (node ids, split keys) is routed in as scalars; a
+flush reads its node's child ids, pivots and count from the device's
+structure mirror, so the flushes that follow one another down the tree
+run as a chain of programs with one device->host read of their count log
+(DESIGN.md §8, "Chained flushes").  Every device computation the index
+launches goes through the ``_device_call`` funnel (``_dispatch``), and every
 device->host wait or read of the fused path through ``_fetch``, so dispatch
 and sync budgets are observable (per-instance ``dispatch_count`` and
 ``sync_count``) and regression-tested.  With a
 :class:`repro.obs.trace.Tracer` attached, each maintenance unit, forced
-maintenance call, dispatch and sync is also a span (``nbtree.unit`` /
-``nbtree.backpressure`` / ``nbtree.dispatch`` / ``nbtree.sync``) in the ring
-buffer and in a running profiler's trace.
+maintenance call, chain of flushes, dispatch and sync is also a span
+(``nbtree.unit`` / ``nbtree.backpressure`` / ``nbtree.chain`` /
+``nbtree.dispatch`` / ``nbtree.sync``) in the ring buffer and in a running
+profiler's trace.
 The pre-fusion eager path is kept under ``fused=False`` as the
 differential-testing and benchmarking baseline
 (``benchmarks/bench_ingest_device.py`` measures the before/after); its own
@@ -207,27 +211,26 @@ def _insert_impl(run_keys, run_vals, run_count, bloom, keys, vals, *,
     return run_keys, run_vals, run_count, bloom
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
-                   static_argnames=("nc", "leaf", "sigma", "sigma_pad",
-                                    "run_cap", "nbits", "h", "interpret"))
-def _flush_impl(run_keys, run_vals, run_count, bloom, nid, child_ids, piv,
-                count, *, nc: int, leaf: bool, sigma: int, sigma_pad: int,
-                run_cap: int, nbits: int, h: int, interpret: bool):
-    """One-dispatch emptying-cascade step for one internal node.
+def _flush_rows(row_k, row_v, count, piv, ck_all, cv_all, counts_all,
+                blooms_all, *, nc: int, leaf: bool, sigma: int,
+                sigma_pad: int, run_cap: int, nbits: int, h: int,
+                interpret: bool):
+    """The rows one flush writes, for a node with ``nc`` children that are
+    leaves (``leaf``) or internal: duplicate-safe cut and pivot partition,
+    one batched merge across the ``nc`` children, vmapped tombstone
+    compaction (leaf level), parent-run compaction, Bloom rebuilds for
+    every touched row.  Untouched children (empty partition) keep rows,
+    counts and filters bit-for-bit, matching the eager path exactly.
 
-    Replaces the eager per-child loop (merge + compact + 3 row writes +
-    full Bloom rebuild per child, with host-synced ``searchsorted`` cuts in
-    the middle, ~25 dispatches at f=4) with a single call: duplicate-safe
-    cut and pivot partition on device, one batched merge across all ``nc``
-    children, vmapped tombstone compaction (leaf level), parent-run
-    compaction, Bloom rebuilds for every touched row.  Untouched children
-    (empty partition) keep rows, counts and filters bit-for-bit, matching
-    the eager path exactly.  Returns the updated tables plus the
-    ``(nc+1,)`` count vector (children then parent) — the only
-    device->host traffic of the whole flush.
+    ``*_all`` hold ``f`` child rows, of which the first ``nc`` are the
+    node's; the rest come back as they went in, with count -1.  Returns
+    the child rows, counts and filters, then the parent's row, count and
+    filter.
     """
-    row_k = run_keys[nid]
-    row_v = run_vals[nid]
+    f = ck_all.shape[0]
+    ck, cv = ck_all[:nc], cv_all[:nc]
+    old_counts, old_blooms = counts_all[:nc], blooms_all[:nc]
+    piv = piv[:nc - 1]
     # ---- duplicate-safe cut (was 2-3 blocking host round trips) -----------
     # Never split a duplicate group across the moved boundary: runs keep
     # duplicate copies newest-first, so flushing the fresh copy while the
@@ -260,8 +263,6 @@ def _flush_impl(run_keys, run_vals, run_count, bloom, nid, child_ids, piv,
     pk, pv = jax.vmap(lambda s, ln: window(s, ln, sigma_pad))(starts, lens)
 
     # ---- one batched merge across all children ----------------------------
-    ck, cv = run_keys[child_ids], run_vals[child_ids]
-    old_counts = run_count[child_ids]
     mk, mv = _merge_batch(pk, pv, ck, cv, interpret=interpret)
     if leaf:
         mk, mv, new_counts = jax.vmap(
@@ -277,19 +278,90 @@ def _flush_impl(run_keys, run_vals, run_count, bloom, nid, child_ids, piv,
     # the scatter-heavy build, and nc <= f is tiny.
     new_blooms = jnp.stack([bloom_build_ref(mk[i], nbits, h)
                             for i in range(nc)])
-    new_blooms = jnp.where(touched[:, None], new_blooms, bloom[child_ids])
+    new_blooms = jnp.where(touched[:, None], new_blooms, old_blooms)
 
     # ---- parent remainder (immediate compaction, DESIGN.md §2) ------------
     rest = count - moved
     rk, rv = window(moved, rest, run_cap)
     pb = bloom_build_ref(rk, nbits, h)
+    return (jnp.concatenate([mk, ck_all[nc:]]),
+            jnp.concatenate([mv, cv_all[nc:]]),
+            jnp.concatenate([new_counts, jnp.full(f - nc, -1, jnp.int32)]),
+            jnp.concatenate([new_blooms, blooms_all[nc:]]),
+            rk, rv, rest, pb)
 
-    run_keys = run_keys.at[child_ids].set(mk).at[nid].set(rk)
-    run_vals = run_vals.at[child_ids].set(mv).at[nid].set(rv)
-    run_count = run_count.at[child_ids].set(new_counts).at[nid].set(rest)
-    bloom = bloom.at[child_ids].set(new_blooms).at[nid].set(pb)
-    counts = jnp.concatenate([new_counts, jnp.reshape(rest, (1,))])
-    return run_keys, run_vals, run_count, bloom, counts
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 7, 8),
+                   static_argnames=("sigma", "sigma_pad", "run_cap", "nbits",
+                                    "h", "interpret"))
+def _flush_impl(run_keys, run_vals, run_count, bloom, pivots, children,
+                nchild, cursor, log, *, sigma: int, sigma_pad: int,
+                run_cap: int, nbits: int, h: int, interpret: bool):
+    """One-dispatch emptying-cascade step for the internal node ``cursor``
+    (an int32 node id on the device; -1: nothing to do).
+
+    Replaces the eager per-child loop (merge + compact + 3 row writes +
+    full Bloom rebuild per child, with host-synced ``searchsorted`` cuts in
+    the middle, ~25 dispatches at f=4) with a single call.  The node's
+    child ids, pivots, fanout and count come from the device tables, and
+    a ``lax.switch`` picks the body specialised for its fanout and child
+    level (:func:`_flush_rows`), or a no-op for cursor -1.
+
+    Returns the updated tables, the next cursor and ``log`` rolled up by
+    one row that holds ``[nid, the f child counts (-1: no child), the
+    node's remaining count]``.  The next cursor is the node the host's
+    rule runs next (DESIGN.md §8, "Chained flushes"): the first largest
+    child, where it holds more than sigma pairs, is internal and has room
+    for a flush in each of its own children; -1 otherwise.  So the host
+    enqueues a chain of these programs without waiting and reads the log
+    once.
+    """
+    f = children.shape[1]
+    n_rows = run_count.shape[0]
+    live = cursor >= 0
+    nid = jnp.maximum(cursor, 0)
+    ids = children[nid]
+    nc = jnp.clip(nchild[nid], 2, f)
+    leaf = nchild[ids[0]] == 0
+    branch = jnp.where(live, 1 + 2 * (nc - 2) + leaf.astype(jnp.int32), 0)
+    static = dict(sigma=sigma, sigma_pad=sigma_pad, run_cap=run_cap,
+                  nbits=nbits, h=h, interpret=interpret)
+
+    def skip(row_k, row_v, count, piv, ck, cv, old_counts, old_blooms):
+        return (ck, cv, jnp.full(f, -1, jnp.int32), old_blooms, row_k, row_v,
+                jnp.int32(-1), old_blooms[0])
+
+    bodies = [skip] + [functools.partial(_flush_rows, nc=c, leaf=lf, **static)
+                       for c in range(2, f + 1) for lf in (False, True)]
+    mk, mv, counts, blooms, rk, rv, rest, pb = jax.lax.switch(
+        branch, bodies, run_keys[nid], run_vals[nid], run_count[nid],
+        pivots[nid], run_keys[ids], run_vals[ids], run_count[ids], bloom[ids])
+
+    # absent children and the no-op write nothing: their index is out of
+    # range and the scatter drops it.
+    tgt = jnp.where(live & (jnp.arange(f) < nc), ids, n_rows)
+    own = jnp.where(live, nid, n_rows)
+    run_keys = run_keys.at[tgt].set(mk, mode="drop").at[own].set(
+        rk, mode="drop")
+    run_vals = run_vals.at[tgt].set(mv, mode="drop").at[own].set(
+        rv, mode="drop")
+    run_count = run_count.at[tgt].set(counts, mode="drop").at[own].set(
+        rest, mode="drop")
+    bloom = bloom.at[tgt].set(blooms, mode="drop").at[own].set(
+        pb, mode="drop")
+
+    # ---- the next unit, by the host's rule ---------------------------------
+    big = jnp.argmax(counts)                       # first largest, as np
+    child = ids[big]
+    grand = children[child]
+    room = (jnp.arange(f) >= nchild[child]) | (
+        run_count[grand] + sigma <= run_cap)
+    chain = (counts[big] > sigma) & (nchild[child] > 0) & jnp.all(room)
+    nxt = jnp.where(chain, child, -1).astype(jnp.int32)
+    row = jnp.concatenate([jnp.reshape(cursor, (1,)), counts,
+                           jnp.reshape(rest, (1,))])
+    log = jnp.roll(log, -1, axis=0).at[-1].set(row)
+    return run_keys, run_vals, run_count, bloom, nxt, log
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
@@ -530,6 +602,13 @@ class NBTreeIndex:
         #: units ``insert_batch`` ran to make room at the root (surfaced as
         #: ``EngineStats.backpressure_units``).
         self.backpressure_units = 0
+        #: flush units that ran as a later link of a chain, with no sync of
+        #: their own (surfaced as ``EngineStats.chained_units``).
+        self.chained_units = 0
+        # the chained flushes' log (see _flush_impl): one row a flush, the
+        # newest last; a chain never runs more flushes than the tree has
+        # levels.
+        self._chain_log = jnp.full((max_levels, f + 2), -1, jnp.int32)
         # Bloom effectiveness (paper Sec. 5.2); see query_batch.
         self.bloom_probes = 0
         self.bloom_negative_skips = 0
@@ -544,7 +623,8 @@ class NBTreeIndex:
 
     # ------------------------------------------------------------ dispatch
     def attach_tracer(self, tracer) -> None:
-        """Record ``nbtree.unit``, ``nbtree.dispatch`` and ``nbtree.sync``
+        """Record ``nbtree.unit``, ``nbtree.backpressure``, ``nbtree.chain``,
+        ``nbtree.dispatch`` and ``nbtree.sync``
         spans on ``tracer`` (a :class:`repro.obs.trace.Tracer`); ``None``
         detaches."""
         self._tracer = tracer
@@ -740,9 +820,11 @@ class NBTreeIndex:
         ``maintain(k)`` once per step, so index upkeep can never stall a
         step for longer than k units — the paper's bounded worst-case
         insertion transplanted to the engine level.  On the fused path a
-        flush unit is ONE device dispatch (plus one tiny count readback)
-        and a split unit at most four — the per-unit dispatch budget is
-        regression-tested.
+        flush unit is ONE device dispatch and a split unit at most four —
+        the per-unit dispatch budget is regression-tested — and the
+        flushes that follow one another down the tree run as one chain
+        with one count readback (:meth:`_flush_chain`).  The ``nbtree.unit``
+        span covers a chain's first unit and holds the rest.
         """
         units = 0
         while self._pending and units < max_units:
@@ -751,13 +833,14 @@ class NBTreeIndex:
                 continue
             node = self._make_room(node)
             requeued, node.requeued = node.requeued, False
+            budget = max_units - units
             if self._tracer is None:
-                units += self._handle_full(node)
+                units += self._handle_full(node, budget)
             else:
                 with self._tracer.span("flush_unit", "nbtree.unit",
                                        kind=self._unit_kind(node),
                                        pairs=node.count, requeued=requeued):
-                    units += self._handle_full(node)
+                    units += self._handle_full(node, budget)
         return len(self._pending)
 
     def _make_room(self, node: _HostNode) -> _HostNode:
@@ -799,16 +882,26 @@ class NBTreeIndex:
             pass
 
     # -------------------------------------------------------- paper operations
-    def _handle_full(self, node: _HostNode) -> int:
-        """One HandleFullSNode step (Sec. 5.1).  Returns work units done."""
-        self.units_done += 1
+    def _handle_full(self, node: _HostNode, budget: int = 1) -> int:
+        """One HandleFullSNode step (Sec. 5.1), or on the fused path a
+        chain of up to ``budget`` flush steps.  Returns work units done."""
         if node.is_leaf:
+            self.units_done += 1
             if node is self.root:
                 self._split_root_leaf()
             else:
                 self._split_upward(node)
             return 1
-        self._flush(node)
+        if self._fused:
+            return self._flush_chain(node, budget)
+        self._flush_eager(node)
+        self._flushed(node)
+        return 1
+
+    def _flushed(self, node: _HostNode) -> None:
+        """Queue what a flush of ``node`` leaves owing; its counts are
+        already taken."""
+        self.units_done += 1
         sizes = [c.count for c in node.children]
         big = int(np.argmax(sizes))
         if sizes[big] > self.sigma:
@@ -817,7 +910,6 @@ class NBTreeIndex:
         if node.count > self.sigma:
             # node absorbed multiple batches; it still owes another flush.
             self._enqueue(node)
-        return 1
 
     def _alloc(self, parent) -> _HostNode:
         if self._next_id >= self.max_nodes:
@@ -833,30 +925,57 @@ class NBTreeIndex:
             self.run_keys, self.run_vals, self.run_count, self.bloom)
         self.max_nodes *= 2
 
-    def _flush(self, node: _HostNode) -> None:
-        """Stream-merge the first sigma live pairs into the children."""
-        if self._fused:
-            self._flush_fused(node)
+    def _flush_chain(self, node: _HostNode, budget: int) -> int:
+        """Flush ``node``, then the flushes the host's rule runs next, as
+        back-to-back ``_flush_impl`` programs that pass the next node on
+        the device (DESIGN.md §8, "Chained flushes"): ``k`` = the budget or
+        the levels from ``node`` down to the leaf parents, whichever is
+        less, then one read of the log.  Replaying the log row by row is
+        what ``maintain`` would have done one unit at a time: each later
+        link is the unit at the front of the queue.  Returns the units
+        run; those after the first count in ``chained_units``."""
+        depth, anc = 0, node.parent
+        while anc is not None:
+            depth, anc = depth + 1, anc.parent
+        k = min(budget, self.height - depth)
+        if self._tracer is None or k == 1:
+            rows = self._launch_chain(node.nid, k)
         else:
-            self._flush_eager(node)
+            with self._tracer.span("dispatch", "nbtree.chain", launched=k):
+                rows = self._launch_chain(node.nid, k)
+        units = 0
+        for nid, *counts, rest in rows:
+            if nid < 0:
+                break                     # the chain stopped on the device
+            if units:
+                node = self._dequeue()
+                room = self._make_room(node)
+                assert node.nid == nid and room is node, \
+                    "chain left the host's unit order"
+                node.requeued = False
+                self.chained_units += 1
+            for child, c in zip(node.children, counts):
+                child.count = c
+                assert c <= self.run_cap, "child run overflow"
+            node.count = rest
+            self._flushed(node)
+            units += 1
+        return units
 
-    def _flush_fused(self, node: _HostNode) -> None:
-        nc = len(node.children)
-        (self.run_keys, self.run_vals, self.run_count, self.bloom,
-         counts) = self._dispatch(
-            _flush_impl, self.run_keys, self.run_vals, self.run_count,
-            self.bloom, jnp.int32(node.nid),
-            jnp.asarray([c.nid for c in node.children], jnp.int32),
-            jnp.asarray([int(k) for k in node.skeys], jnp.uint32),
-            jnp.int32(node.count),
-            nc=nc, leaf=node.children[0].is_leaf, sigma=self.sigma,
-            sigma_pad=self.sigma_pad, run_cap=self.run_cap,
-            nbits=self.nbits, h=self.h, interpret=ops._interpret())
-        counts = self._fetch("flush_counts", counts)  # the flush's one sync
-        for child, c in zip(node.children, counts[:-1].tolist()):
-            child.count = int(c)
-            assert child.count <= self.run_cap, "child run overflow"
-        node.count = int(counts[-1])
+    def _launch_chain(self, nid: int, k: int) -> list:
+        """``k`` chained flush programs from node ``nid`` and the one read
+        of their log (``flush_counts``); returns their ``k`` rows."""
+        cursor, log = jax.device_put(np.int32(nid)), self._chain_log
+        for _ in range(k):
+            (self.run_keys, self.run_vals, self.run_count, self.bloom,
+             cursor, log) = self._dispatch(
+                _flush_impl, self.run_keys, self.run_vals, self.run_count,
+                self.bloom, self.pivots, self.children, self.nchild, cursor,
+                log, sigma=self.sigma, sigma_pad=self.sigma_pad,
+                run_cap=self.run_cap, nbits=self.nbits, h=self.h,
+                interpret=ops._interpret())
+        self._chain_log = log
+        return self._fetch("flush_counts", log)[-k:].tolist()  # one sync
 
     def _flush_eager(self, node: _HostNode) -> None:
         """Pre-fusion write path: ~25 dispatches + host syncs per flush."""

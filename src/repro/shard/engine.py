@@ -77,8 +77,8 @@ class ShardedEngine(StorageEngine):
         # monotone counters of shards retired by rebalances
         # (io_s, seeks, rd, wr, bloom probes / skips / false positives,
         #  maintain units, maintain wall seconds, device dispatches,
-        #  device syncs)
-        self._retired = [0.0, 0, 0, 0, 0, 0, 0, 0, 0.0, 0, 0, 0]
+        #  device syncs, backpressure units, chained units)
+        self._retired = [0.0, 0, 0, 0, 0, 0, 0, 0, 0.0, 0, 0, 0, 0]
         self._tracer = None
         if partition == "hash":
             self.partitioner = HashPartitioner(shards)
@@ -281,6 +281,7 @@ class ShardedEngine(StorageEngine):
         self._retired[9] += st.device_dispatches
         self._retired[10] += st.device_syncs
         self._retired[11] += st.backpressure_units
+        self._retired[12] += st.chained_units
         lineage_s = self._inherited_s[sid] + eng.io_time_s()
         left = rk < np.uint64(q)
         a, b = self._make_shard(), self._make_shard()
@@ -393,4 +394,6 @@ class ShardedEngine(StorageEngine):
             device_syncs=self._retired[10] + sum(s.device_syncs for s in per),
             backpressure_units=(self._retired[11]
                                 + sum(s.backpressure_units for s in per)),
+            chained_units=(self._retired[12]
+                           + sum(s.chained_units for s in per)),
             applied_lsn=self.applied_lsn)

@@ -15,6 +15,9 @@
 * **Backpressure** — ``backpressure_units`` counts the units a write run
   forces inside ``apply`` (none for commits of at most sigma), each inside
   an ``nbtree.backpressure`` span nested in ``nbtree.apply``.
+* **Chains** — a forced ``maintain(4)`` runs the flushes down the tree as
+  one chain of programs with one ``flush_counts`` sync; units with an
+  ``nbtree.unit`` span plus ``chained_units`` equal ``units_done``.
 """
 import glob
 
@@ -210,9 +213,11 @@ def test_backpressure_counter_zero_for_commits_up_to_sigma():
 def test_backpressure_counter_and_span_inside_apply(tmp_path):
     """A commit of 4,000 ascending inserts (padded to 4,096: 64 chunks of
     sigma) runs its forced maintenance inside apply:
-    ``backpressure_units`` counts exactly the units run there, each inside an ``nbtree.backpressure`` span that
-    nests in ``nbtree.apply``; split halves put back on the queue show as
-    units with ``requeued``."""
+    ``backpressure_units`` counts exactly the units run there, each inside
+    an ``nbtree.backpressure`` span that nests in ``nbtree.apply``: those
+    with an ``nbtree.unit`` span of their own and the later links of
+    chains; split halves put back on the queue show as units with
+    ``requeued``."""
     import jax
 
     eng = make_engine("jax-nbtree", **TINY)
@@ -222,7 +227,7 @@ def test_backpressure_counter_and_span_inside_apply(tmp_path):
     keys = np.arange(1, 8001, dtype=np.uint64) * 5
     eng.apply(OpBatch.inserts(keys[:4000], np.arange(4000)))   # compiles
     eng.drain()
-    b0, u0 = idx.backpressure_units, idx.units_done
+    b0, u0, c0 = idx.backpressure_units, idx.units_done, idx.chained_units
     n_bp = len(tr.spans("cascade"))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -242,7 +247,8 @@ def test_backpressure_counter_and_span_inside_apply(tmp_path):
     units = [(s, e, st) for n, s, e, st in events if n == "nbtree.unit"]
     assert bp and all(apply_[0] <= s and e <= apply_[1] for s, e, _ in bp)
     assert all(st["units"] == 4 and 0 <= st["chunk"] < 64 for *_, st in bp)
-    assert len(units) == forced
+    # a chain's later links run inside its first unit's span
+    assert len(units) + idx.chained_units - c0 == forced
     assert all(any(bs <= s and e <= be for bs, be, _ in bp)
                for s, e, _ in units)
     assert len(tr.spans("cascade")) - n_bp == len(bp)
@@ -251,3 +257,60 @@ def test_backpressure_counter_and_span_inside_apply(tmp_path):
     requeued = [e["args"] for e in tr.spans("flush_unit")
                 if e["args"]["requeued"]]
     assert requeued and all(a["pairs"] > idx.sigma for a in requeued)
+
+
+def test_backpressure_chain_is_one_flush_counts_sync():
+    """Under backpressure, ``maintain(4)`` runs a chain of flushes down the
+    right spine as back-to-back programs with one ``flush_counts`` sync: a
+    call whose units are k >= 2 flushes costs exactly one sync, every chain
+    costs one, and the units with an ``nbtree.unit`` span plus
+    ``chained_units`` equal ``units_done``."""
+    eng = make_engine("jax-nbtree", **TINY)
+    idx = eng.idx
+    tr = Tracer()
+    eng.attach_tracer(tr)
+    keys = np.arange(1, 12001, dtype=np.uint64) * 5
+    eng.apply(OpBatch.inserts(keys[:4000], np.arange(4000)))   # compiles
+    eng.drain()
+    whats: list = []
+    fetch, maintain = idx._fetch, idx.maintain
+
+    def fetch_named(what, x, read=np.asarray):
+        whats.append(what)
+        return fetch(what, x, read)
+
+    calls: list = []
+
+    def maintain_logged(max_units=1):
+        w0, u0, s0 = len(whats), idx.units_done, len(tr.spans("flush_unit"))
+        c0 = len([e for e in tr.spans("dispatch")
+                  if e["name"] == "nbtree.chain"])
+        pending = maintain(max_units)
+        units = tr.spans("flush_unit")[s0:]
+        chains = [e for e in tr.spans("dispatch")
+                  if e["name"] == "nbtree.chain"][c0:]
+        calls.append((max_units, idx.units_done - u0,
+                      [u["args"]["kind"] for u in units], whats[w0:],
+                      [c["args"]["launched"] for c in chains]))
+        return pending
+
+    idx._fetch, idx.maintain = fetch_named, maintain_logged
+    u0, c0 = idx.units_done, idx.chained_units
+    s0 = len(tr.spans("flush_unit"))
+    for i in range(4000, len(keys), 4000):
+        eng.apply(OpBatch.inserts(keys[i:i + 4000], np.arange(4000)))
+        eng.maintain(1)
+    spans = len(tr.spans("flush_unit")) - s0
+    chained = idx.chained_units - c0
+    assert chained > 0
+    assert spans + chained == idx.units_done - u0
+    one_chain = 0
+    for budget, units, kinds, syncs, launched in calls:
+        assert syncs.count("flush_counts") == kinds.count("flush")
+        assert len(launched) <= kinds.count("flush")
+        assert all(2 <= k <= min(budget, idx.height) for k in launched)
+        if budget == 4 and units >= 2 and kinds == ["flush"]:
+            assert syncs == ["flush_counts"] and launched
+            one_chain += 1
+    assert one_chain > 0
+    assert eng.stats().chained_units == idx.chained_units

@@ -16,6 +16,7 @@ Three contracts of the one-dispatch maintenance path:
   run, at every step and for every node row after drain.
 """
 import numpy as np
+import pytest
 
 import repro.core.jax_nbtree as jnb
 from repro.core.jax_nbtree import NBTreeIndex, _build_bloom
@@ -209,3 +210,194 @@ def test_maintain_budget_still_bounded_fused():
     assert max_drop <= 1
     idx.drain()
     idx.check_invariants()
+
+
+# ------------------------------------------------------------ chained flushes
+#: f=4, sigma=64: a run is one 1,024-key tile (interpret-mode Pallas on the
+#: CPU), 512 rows never grow, and 2,400 keys make a tree of height 3.
+CHAIN = {"f": 4, "sigma": 64, "max_nodes": 512}
+
+
+def _keys(order: str, n: int = 2400) -> np.ndarray:
+    keys = np.arange(1, n + 1, dtype=np.uint32) * 3
+    if order == "descending":
+        return keys[::-1].copy()
+    if order == "uniform":
+        return np.random.default_rng(11).permutation(keys)
+    return keys
+
+
+def _pair():
+    return (NBTreeIndex(fused=True, **CHAIN),
+            NBTreeIndex(fused=False, **CHAIN))
+
+
+def _stop_reason(idx: NBTreeIndex, last) -> str:
+    """Why the host's rule runs no further flush right after ``last``'s:
+    its largest child is a leaf to split, lacks room in a child of its own
+    (a room-first descent), holds at most sigma pairs while the front of
+    the queue is a stale entry, or none of these (``budget``: the rule
+    would flush that child next)."""
+    sizes = [c.count for c in last.children]
+    big = last.children[int(np.argmax(sizes))]
+    if big.count > idx.sigma:
+        if big.is_leaf:
+            return "leaf_split"
+        if any(g.count + idx.sigma > idx.run_cap for g in big.children):
+            return "room_first"
+        return "budget"
+    if idx._pending and idx._pending[0].count <= idx.sigma:
+        return "stale_entry"
+    return "other"
+
+
+def _host_next(idx: NBTreeIndex, node) -> int:
+    """The node the host's rule flushes right after ``node``'s flush, with
+    nothing between (-1: none): its first largest child, where that holds
+    more than sigma pairs, is internal and has room in each child."""
+    sizes = [c.count for c in node.children]
+    big = node.children[int(np.argmax(sizes))]
+    if (big.count > idx.sigma and not big.is_leaf and all(
+            g.count + idx.sigma <= idx.run_cap for g in big.children)):
+        return big.nid
+    return -1
+
+
+def _record_chains(idx: NBTreeIndex) -> list:
+    """(units, stop reason) of every chain ``idx`` runs from now on; each
+    flush program's next cursor must be the host rule's next node."""
+    chains: list = []
+    chain, flushed, dispatch = idx._flush_chain, idx._flushed, idx._dispatch
+    last: list = []
+    device: list = []      # (cursor in, cursor out) of each flush program
+    host: list = []        # the host rule's next node after each flush
+
+    def record_dispatch(fn, *args, **kwargs):
+        if fn is not jnb._flush_impl:
+            return dispatch(fn, *args, **kwargs)
+        cursor = int(np.asarray(args[7]))
+        out = dispatch(fn, *args, **kwargs)
+        device.append((cursor, int(np.asarray(out[4]))))
+        return out
+
+    def record_flushed(node):
+        last[:] = [node]
+        flushed(node)
+        host.append(_host_next(idx, node))
+
+    def record_chain(node, budget):
+        units = chain(node, budget)
+        assert [nxt for cur, nxt in device if cur >= 0] == host
+        assert all(nxt == -1 for cur, nxt in device if cur < 0)
+        device.clear()
+        host.clear()
+        chains.append((units, _stop_reason(idx, last[0])))
+        return units
+
+    idx._flush_chain, idx._flushed = record_chain, record_flushed
+    idx._dispatch = record_dispatch
+    return chains
+
+
+@pytest.mark.parametrize("budget", [1, 4, 64])
+@pytest.mark.parametrize("order", ["ascending", "descending", "uniform"])
+def test_chained_maintain_matches_eager(order, budget):
+    """Chains against the one-unit-at-a-time eager oracle: every row,
+    count, filter, structure mirror and queue entry equal after every
+    ``maintain(budget)`` and after every commit, whose forced maintenance
+    (commits over sigma) chains too; with deletes of earlier keys."""
+    fused, eager = _pair()
+    chains = _record_chains(fused)
+    keys = _keys(order)
+    batches = (48, 300)                  # sigma-sized, then backpressure
+    i = r = 0
+    while i < len(keys):
+        ks = keys[i:i + batches[r % 2]]
+        for idx in (fused, eager):
+            idx.insert_batch(ks, np.arange(len(ks), dtype=np.int32) + i)
+            if r % 3 == 2:
+                idx.delete_batch(keys[i - 40:i - 20])
+        _assert_same_tables(fused, eager, f"commit {r}")
+        for idx in (fused, eager):
+            idx.maintain(budget)
+        _assert_same_tables(fused, eager, f"maintain {r}")
+        i, r = i + len(ks), r + 1
+    fused.drain()
+    eager.drain()
+    _assert_same_tables(fused, eager, "drained")
+    fused.check_invariants()
+    assert fused.units_done == eager.units_done
+    assert max(u for u, _ in chains) >= 2 and fused.chained_units > 0
+    assert fused.chained_units == sum(u - 1 for u, _ in chains)
+    assert eager.chained_units == 0
+
+
+def _make_tight_grandchild(idx: NBTreeIndex) -> None:
+    """Drive ``idx`` to a room-first state: a root flush whose largest
+    child A is internal and over sigma, with A's last child B short of
+    room for a flush.  Ascending keys all go down the right spine; single
+    flushes of the root and of A, with the queue emptied after each,
+    pile pairs into B that no flush of B takes out."""
+    keys = iter(range(10**6, 2 * 10**6, 3))
+
+    def put(n):
+        ks = np.fromiter(keys, np.uint32, n)
+        idx.insert_batch(ks, np.arange(n, dtype=np.int32))
+
+    def single_flush(node):
+        idx._handle_full(node, 1)
+        idx._pending.clear()
+        idx._pending_n.clear()
+
+    for k in range(0, 2400, 48):
+        put(48)
+        idx.maintain(4)
+    idx.drain()
+    a = idx.root.children[-1]
+    b = a.children[-1]
+    assert not b.is_leaf                 # height 3: root, A, B, leaves
+    while b.count + idx.sigma <= idx.run_cap:
+        put(idx.sigma)
+        single_flush(idx.root)
+        single_flush(a)
+    while a.count <= idx.sigma:
+        put(idx.sigma)
+        single_flush(idx.root)
+    put(idx.sigma + 1)                   # the root owes a flush again
+
+
+@pytest.mark.parametrize("stop", ["leaf_split", "stale_entry", "budget",
+                                  "room_first"])
+def test_chain_stops_where_the_host_rule_does(stop):
+    """A chain ends exactly where the host's rule would run something
+    other than the largest child's flush, and the eager oracle, one unit
+    at a time, ends in the same tables: at a leaf split, at a stale entry,
+    at the budget (with more levels below), at a room-first descent."""
+    fused, eager = _pair()
+    if stop == "room_first":
+        for idx in (fused, eager):
+            _make_tight_grandchild(idx)
+        _assert_same_tables(fused, eager, "set-up")
+        chains = _record_chains(fused)
+        for idx in (fused, eager):
+            idx.maintain(4)
+        _assert_same_tables(fused, eager, "room first")
+        assert chains[0] == (1, "room_first")
+    else:
+        order, batch, budget = {"leaf_split": ("ascending", 300, 4),
+                                "stale_entry": ("descending", 300, 4),
+                                "budget": ("uniform", 64, 2)}[stop]
+        chains = _record_chains(fused)
+        keys = _keys(order)
+        for i in range(0, len(keys), batch):
+            for idx in (fused, eager):
+                idx.insert_batch(keys[i:i + batch],
+                                 np.arange(len(keys[i:i + batch]),
+                                           dtype=np.int32))
+                idx.maintain(budget)
+            _assert_same_tables(fused, eager, f"commit at {i}")
+        assert any(r == stop and u >= (budget if stop == "budget" else 1)
+                   for u, r in chains), chains
+    fused.drain()
+    eager.drain()
+    _assert_same_tables(fused, eager, "drained")

@@ -4,10 +4,10 @@ Ascending (time-keyed), descending and hashed key streams go through the
 served path, ``make_engine("jax-nbtree")`` -> ``apply`` / ``maintain``,
 on the fused and the eager write path, with commits of sigma
 (``maintain(1)`` after each) and commits larger than sigma (the
-backpressure maintenance inside ``insert_batch``).  After every unit the
-tree keeps its structure and run bounds and holds exactly the pairs
-inserted so far, and no flush ever targets a child without room for
-sigma more pairs.  At the end the engine answers like a dict and like the
+backpressure maintenance inside ``insert_batch``, whose flushes run as
+chains).  After every unit, or chain of units, the tree keeps its
+structure and run bounds and holds exactly the pairs inserted so far, and
+no flush ever targets a child without room for sigma more pairs.  At the end the engine answers like a dict and like the
 cost-model ``refimpl``.  Last, the ``ordered.insert`` benchmark cell is
 rehearsed at a tiny size through the harness.
 """
@@ -37,10 +37,12 @@ def _stream(order: str) -> np.ndarray:
 
 
 def _watch(eng, oracle: dict) -> dict:
-    """Check the index after every unit; keep ``oracle`` equal to the pairs
-    inserted so far; refuse a flush into a child without room."""
+    """Check the index after every unit or chain; keep ``oracle`` equal to
+    the pairs inserted so far; refuse a flush into a child without room.
+    Every flush, a chain's later links included, is the node that
+    ``_make_room`` hands back, right before the flush runs."""
     idx = eng.idx
-    handle, flush, insert = idx._handle_full, idx._flush, idx._insert_chunk
+    handle, room, insert = idx._handle_full, idx._make_room, idx._insert_chunk
     seen = {"units": 0, "flushes": 0}
 
     def insert_chunk(keys, vals):
@@ -49,15 +51,17 @@ def _watch(eng, oracle: dict) -> dict:
                           np.asarray(vals).tolist()))
 
     def flush_into_room(node):
-        for c in node.children:
-            assert c.count + idx.sigma <= idx.run_cap, \
-                "flush into a child without room"
-        seen["flushes"] += 1
-        flush(node)
+        node = room(node)
+        if not node.is_leaf:
+            for c in node.children:
+                assert c.count + idx.sigma <= idx.run_cap, \
+                    "flush into a child without room"
+            seen["flushes"] += 1
+        return node
 
-    def checked_unit(node):
-        units = handle(node)
-        seen["units"] += 1
+    def checked_unit(node, budget=1):
+        units = handle(node, budget)
+        seen["units"] += units
         idx.check_invariants()
         got_k, got_v = eng.dump_live()
         want = sorted(oracle.items())
@@ -65,7 +69,7 @@ def _watch(eng, oracle: dict) -> dict:
         assert got_v.tolist() == [v for _, v in want]
         return units
 
-    idx._insert_chunk, idx._flush = insert_chunk, flush_into_room
+    idx._insert_chunk, idx._make_room = insert_chunk, flush_into_room
     idx._handle_full = checked_unit
     return seen
 

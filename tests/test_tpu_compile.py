@@ -8,6 +8,8 @@ described inside a fixture, so importing this file touches no TPU library;
 the persistent compilation cache is off around the compiles, since an
 entry compiled for a chip cannot be read back without one.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -89,16 +91,33 @@ def test_insert_impl_holds_merge_kernel(one_chip, idx, rows):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("leaf", [True, False], ids=["leaf", "internal"])
-def test_flush_impl_holds_merge_kernel(one_chip, idx, rows, leaf):
+@pytest.fixture(scope="module")
+def flush_text(one_chip, idx, rows):
+    """The compiled chained flush program (a device cursor and log)."""
     S = _spec(one_chip)
-    text = _compiled_text(
-        J._flush_impl, *_tables(S, idx, rows), S((), jnp.int32),
-        S((FANOUT,), jnp.int32), S((FANOUT - 1,), jnp.uint32),
-        S((), jnp.int32), nc=FANOUT, leaf=leaf, sigma=SIGMA,
+    return _compiled_text(
+        J._flush_impl, *_tables(S, idx, rows),
+        S((rows, FANOUT - 1), jnp.uint32), S((rows, FANOUT), jnp.int32),
+        S((rows,), jnp.int32), S((), jnp.int32),
+        S((idx.max_levels, FANOUT + 2), jnp.int32), sigma=SIGMA,
         sigma_pad=idx.sigma_pad, run_cap=idx.run_cap, nbits=idx.nbits,
         h=idx.h, interpret=False)
-    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("leaf", [True, False], ids=["leaf", "internal"])
+def test_flush_impl_holds_merge_kernel(flush_text, rows, leaf):
+    """One branch of the flush program per fanout 2..f, for leaf children
+    (those compact: a sort) and for internal ones, each with the merge
+    kernel, beside the no-op branch; and no copy of a whole node table."""
+    comps = {c.split(" ", 1)[0]: c
+             for c in re.split(r"\n(?=%)", flush_text)}
+    (cond,) = [ln for ln in flush_text.splitlines() if " conditional(" in ln]
+    branches = re.search(r"branch_computations=\{([^}]*)\}",
+                         cond).group(1).split(", ")
+    assert len(branches) == 1 + 2 * (FANOUT - 1)
+    merging = [comps[b] for b in branches if "tpu_custom_call" in comps[b]]
+    assert sum(("sort(" in c) == leaf for c in merging) == FANOUT - 1
+    assert not re.search(rf"= \w+\[{rows},\d+\]\S* copy\(", flush_text)
 
 
 def test_query_batch_impl_compiles(one_chip, idx, rows):

@@ -1,11 +1,11 @@
 """Replicated open-loop frontend: partitioned serving with failover.
 
-The replicated sibling of :class:`repro.ingest.frontend.IngestFrontend`
-(DESIGN.md §12): the key space is range-partitioned into replica groups
+DESIGN.md §12: the key space is range-partitioned into replica groups
 (:class:`~repro.replication.replica.ReplicaGroup`), each a primary +
-R−1 replicas kept in sync by WAL shipping.  The serving loop runs the
-same deterministic sim clock, group commit, and admission control as the
-single-engine frontend, plus the failure machinery:
+R−1 replicas kept in sync by WAL shipping.  :class:`ReplicatedFrontend`
+is the commit seam of the shared serving loop
+(:func:`repro.ingest.frontend.serve`) for such an ensemble — one leg per
+group an op touches — plus the failure machinery:
 
 * **Heartbeats** — every live node beats the shared
   :class:`~repro.distributed.fault_tolerance.HeartbeatMonitor` at each
@@ -38,22 +38,24 @@ import numpy as np
 from repro.core.engine_api import OpBatch, OpKind
 from repro.distributed.fault_tolerance import HeartbeatMonitor
 from repro.ingest.arrivals import ArrivalTrace
-from repro.ingest.frontend import FrontendConfig
-from repro.ingest.slo import SLOTracker
+from repro.ingest.frontend import (KIND_NAMES, WRITE_KINDS, CommitSeam,
+                                   FrontendConfig, SharedFifo, rows_at,
+                                   serve, write_rows)
 from repro.obs.metrics import ObsConfig, WindowedMetrics
-from repro.obs.trace import Tracer
 from repro.shard.partition import RangePartitioner
 from repro.wal.faults import FaultSchedule
 
 from .replica import ReplicaGroup, ReplicationConfig
 
-_KIND_NAMES = {int(k): k.name.lower() for k in OpKind}
-_WRITE_KINDS = (int(OpKind.INSERT), int(OpKind.DELETE))
 _RANGE = int(OpKind.RANGE)
 
 
-class ReplicatedFrontend:
-    """Open-loop serving over replicated range partitions; see module doc."""
+class ReplicatedFrontend(CommitSeam):
+    """Open-loop serving over replicated range partitions; see module doc.
+
+    It is the commit seam for replica groups: each commit runs one leg
+    per group it touches, and ops whose range cannot commit are parked.
+    """
 
     def __init__(self, engine_factory, directory: str, *, groups: int = 4,
                  replication: ReplicationConfig | None = None,
@@ -61,15 +63,12 @@ class ReplicatedFrontend:
                  chaos: FaultSchedule | None = None,
                  obs: ObsConfig | None = None,
                  window_s: float = 0.05, key_hi: int = 1 << 20):
+        super().__init__(config, obs)
         self._factory = engine_factory
         self.dir = directory
         self.n_groups_requested = int(groups)
         self.rep = replication or ReplicationConfig()
-        self.config = config or FrontendConfig()
         self.chaos = chaos
-        self.obs = obs if (obs is not None and obs.enabled) else None
-        self.tracer = Tracer(capacity=self.obs.trace_capacity) \
-            if self.obs is not None else None
         self.window_s = float(window_s)
         self.key_hi = int(key_hi)
         self.monitor = HeartbeatMonitor(
@@ -82,22 +81,20 @@ class ReplicatedFrontend:
         #: here iff its quorum fsync returned, i.e. iff it was acked).
         self.acked: list = []
         self.shed_unavailable = 0
+        self._parked: list[list] = []   # [i, next_t, backoff, deadline]
 
     # -------------------------------------------------------------- topology
     def _bootstrap(self, trace: ArrivalTrace) -> None:
         """Fix the routing table and spawn every group's initial nodes."""
+        n = self.n_groups_requested
+        ins = trace.ops.keys[np.asarray(trace.ops.kinds) == int(OpKind.INSERT)]
         if len(trace.preload):
             self.partitioner = RangePartitioner.from_sample(
-                trace.preload.keys, self.n_groups_requested)
+                trace.preload.keys, n)
+        elif len(ins) >= 2 * n:
+            self.partitioner = RangePartitioner.from_sample(ins[:4096], n)
         else:
-            ins = trace.ops.keys[np.asarray(trace.ops.kinds)
-                                 == int(OpKind.INSERT)]
-            if len(ins) >= 2 * self.n_groups_requested:
-                self.partitioner = RangePartitioner.from_sample(
-                    ins[:4096], self.n_groups_requested)
-            else:
-                self.partitioner = RangePartitioner.even(
-                    self.n_groups_requested, self.key_hi)
+            self.partitioner = RangePartitioner.even(n, self.key_hi)
         for gid in range(self.partitioner.n_shards):
             lo, hi = self.partitioner.interval(gid)
             g = ReplicaGroup(gid, os.path.join(self.dir, f"g{gid}"),
@@ -140,11 +137,8 @@ class ReplicatedFrontend:
                 if node.alive:
                     self.monitor.beat(node.node_id, now)
         for host in self.monitor.advance(now):
-            entry = self._node_of.get(host)
-            if entry is None:
-                continue
-            g, node = entry
-            if g.failed or node not in g.nodes:
+            g, node = self._node_of.get(host, (None, None))
+            if g is None or g.failed or node not in g.nodes:
                 continue
             if node is g.primary:
                 g.promote(now)
@@ -171,27 +165,76 @@ class ReplicatedFrontend:
                     and (g.primary is None or not g.primary.alive):
                 g.pending_down_t = now
             if wa and g.pending_down_t is not None:
-                t0 = g.pending_down_t
+                g.downtime_s += now - g.pending_down_t
                 g.pending_down_t = None
-                g.downtime_s += now - t0
-                for ev in reversed(g.failovers):
-                    if ev["t_write_restored"] is None:
-                        ev["t_write_restored"] = float(now)
-                        ev["rto_s"] = float(now - ev["t_crash"])
-                        if self.tracer is not None:
-                            self.tracer.complete(
-                                "failover", "primary_failover",
-                                ev["t_crash"], ev["rto_s"], gid=g.gid,
-                                new_primary=ev["new_primary"],
-                                replayed_ops=ev["replayed_ops"])
-                    break
+                ev = g.failovers[-1] if g.failovers else None
+                if ev is not None and ev["t_write_restored"] is None:
+                    ev["t_write_restored"] = float(now)
+                    ev["rto_s"] = float(now - ev["t_crash"])
+                    if self.tracer is not None:
+                        self.tracer.complete(
+                            "failover", "primary_failover", ev["t_crash"],
+                            ev["rto_s"], gid=g.gid,
+                            new_primary=ev["new_primary"],
+                            replayed_ops=ev["replayed_ops"])
 
-    def _next_event_time(self, now: float, parked, t_arr, n) -> float | None:
-        """Earliest instant anything can change while the queue is empty."""
-        cands = []
-        if self._i < n:
-            cands.append(float(t_arr[self._i]))
-        cands.extend(p[1] for p in parked)
+    # --------------------------------------------------------------- routing
+    def _route(self, kind, key, hi, point_gid) -> list[int]:
+        if int(kind) == _RANGE:
+            return list(self.partitioner.shards_for_range(int(key), int(hi)))
+        return [int(point_gid)]
+
+    def _gids_of(self, i: int) -> list[int]:
+        ops = self._ops
+        return self._route(ops.kinds[i], ops.keys[i], ops.his[i],
+                           self._point_gid[i])
+
+    def _admissible(self, i: int, now: float) -> bool:
+        groups = [self.groups[gid] for gid in self._gids_of(i)]
+        if int(self._ops.kinds[i]) in WRITE_KINDS:
+            return all(g.write_available(now) for g in groups)
+        return all(g.read_available(now) for g in groups)
+
+    def _doomed(self, i: int) -> bool:
+        """True when the op targets a permanently failed group."""
+        return any(self.groups[gid].failed for gid in self._gids_of(i))
+
+    def _shed_parked(self, i: int, now: float) -> None:
+        kname = KIND_NAMES[int(self._ops.kinds[i])]
+        self.tracker.record_shed(kname)
+        self.shed_unavailable += 1
+        for gid in self._gids_of(i):
+            self._gwm[gid].record_shed(now)
+        if self.tracer is not None:
+            self.tracer.instant("shed", f"unavailable_{kname}", now)
+
+    # ------------------------------------------------------------ commit seam
+    @property
+    def parked(self) -> int:
+        return len(self._parked)
+
+    def advance(self, now: float, queue) -> None:
+        """Failure machinery up to ``now``, then retry every parked op due:
+        shed it past its deadline, requeue it if its range is back, else
+        back off exponentially."""
+        self._tick(now)
+        for p in list(self._parked):
+            i, next_t, backoff, deadline = p
+            if self._doomed(i) or (next_t <= now and now >= deadline):
+                self._parked.remove(p)
+                self._shed_parked(i, now)
+            elif next_t <= now:
+                if self._admissible(i, now):
+                    self._parked.remove(p)
+                    queue.requeue(0, i)
+                else:
+                    p[2] = min(backoff * 2, self.rep.retry_backoff_max_s)
+                    p[1] = min(now + p[2], deadline)
+
+    def wakeups(self, now: float) -> list:
+        """Parked retries, chaos events, rebuild completions, promotion
+        gates and heartbeat timeouts still pending."""
+        cands = [p[1] for p in self._parked]
         if self.chaos is not None and self.chaos.next_time is not None:
             cands.append(self.chaos.next_time)
         for g in self.groups:
@@ -202,203 +245,90 @@ class ReplicatedFrontend:
                 if not node.alive and node.node_id not in self.monitor.dead:
                     beat = self.monitor.last_beat.get(node.node_id, 0.0)
                     cands.append(beat + self.monitor.timeout)
-        future = [c for c in cands if c > now]
-        return min(future) if future else None
+        return cands
 
-    # --------------------------------------------------------------- routing
-    def _gids_of(self, i: int, kinds, keys, his) -> list[int]:
-        if int(kinds[i]) == _RANGE:
-            return list(self.partitioner.shards_for_range(int(keys[i]),
-                                                          int(his[i])))
-        return [int(self._point_gid[i])]
-
-    def _admissible(self, i: int, now: float, kinds, keys, his) -> bool:
-        write = int(kinds[i]) in _WRITE_KINDS
-        for gid in self._gids_of(i, kinds, keys, his):
-            g = self.groups[gid]
-            ok = g.write_available(now) if write else g.read_available(now)
-            if not ok:
-                return False
-        return True
-
-    def _doomed(self, i: int, kinds, keys, his) -> bool:
-        """True when the op targets a permanently failed group."""
-        return any(self.groups[gid].failed
-                   for gid in self._gids_of(i, kinds, keys, his))
-
-    # ----------------------------------------------------------------- serve
-    def run(self, trace: ArrivalTrace, *, drain: bool = True) -> dict:
-        cfg, rep = self.config, self.rep
-        self._bootstrap(trace)
-        tracker = SLOTracker(stall_factor=cfg.stall_factor)
-        gwm = [WindowedMetrics(self.window_s) for _ in self.groups]
-        wm = WindowedMetrics(self.obs.window_s, stall_k=self.obs.stall_k,
-                             stall_trailing=self.obs.stall_trailing) \
-            if self.obs is not None else None
-
-        kinds = np.asarray(trace.ops.kinds)
-        keys_a, vals_a, his_a = (trace.ops.keys, trace.ops.vals,
-                                 trace.ops.his)
-        t_arr = np.asarray(trace.t_arrive, np.float64)
-        n = len(kinds)
-        self._point_gid = self.partitioner.shard_of(keys_a)
-        queue: list[int] = []
-        parked: list[list] = []     # [idx, next_t, backoff, park_deadline]
-        self._i = 0
-        t_free = 0.0
-
-        def admit_until(t: float) -> None:
-            i = self._i
-            while i < n and t_arr[i] <= t:
-                if len(queue) < cfg.max_queue:
-                    queue.append(i)
-                    tracker.record_queue_depth(len(queue))
-                else:
-                    tracker.record_shed(_KIND_NAMES[int(kinds[i])])
-                i += 1
-            self._i = i
-
-        def park(i: int, now: float) -> None:
-            parked.append([i, now + rep.retry_backoff_s,
-                           rep.retry_backoff_s,
-                           now + rep.retry_deadline_s])
-
-        def shed_parked(i: int, now: float) -> None:
-            kname = _KIND_NAMES[int(kinds[i])]
-            tracker.record_shed(kname)
-            self.shed_unavailable += 1
-            for gid in self._gids_of(i, kinds, keys_a, his_a):
-                gwm[gid].record_shed(now)
-            if self.tracer is not None:
-                self.tracer.instant("shed", f"unavailable_{kname}", now)
-
-        def retry_parked(now: float) -> None:
-            for p in list(parked):
-                i, next_t, backoff, deadline = p
-                if self._doomed(i, kinds, keys_a, his_a) or \
-                        (next_t <= now and now >= deadline):
-                    parked.remove(p)
-                    shed_parked(i, now)
-                elif next_t <= now:
-                    if self._admissible(i, now, kinds, keys_a, his_a):
-                        parked.remove(p)
-                        queue.append(i)
-                    else:
-                        p[2] = min(backoff * 2, rep.retry_backoff_max_s)
-                        p[1] = min(now + p[2], deadline)
-
-        while queue or parked or self._i < n:
-            now = t_free
-            self._tick(now)
-            admit_until(now)
-            retry_parked(now)
-            if not queue:
-                nxt = self._next_event_time(now, parked, t_arr, n)
-                if nxt is None:
-                    break               # nothing left can ever happen
-                t_free = nxt
-                continue
-            t0 = max(t_free, t_arr[queue[0]])
-
-            # group commit: size or linger deadline, whichever first.
-            if len(queue) >= cfg.commit_ops or self._i >= n:
-                t_commit = t0
+    def take(self, queue, max_ops: int, t_commit: float) -> list:
+        """Take admissible ops in order; park the rest (their range is
+        down — the queue must not head-of-line-block other ranges)."""
+        self._tick(t_commit)
+        rep, take = self.rep, []
+        while queue.backlog() and len(take) < max_ops:
+            (tid, i), = queue.take(1)
+            if self._doomed(i):
+                self._shed_parked(i, t_commit)
+            elif self._admissible(i, t_commit):
+                take.append((tid, i))
             else:
-                deadline = t0 + cfg.linger_s
-                need = cfg.commit_ops - len(queue)
-                j, got = self._i, 0
-                while j < n and t_arr[j] <= deadline and got < need:
-                    j, got = j + 1, got + 1
-                t_commit = max(t0, t_arr[j - 1]) if got == need else deadline
-            admit_until(t_commit)
-            self._tick(t_commit)
+                self._parked.append([i, t_commit + rep.retry_backoff_s,
+                                     rep.retry_backoff_s,
+                                     t_commit + rep.retry_deadline_s])
+        return take
 
-            # take admissible ops in order; park the rest (their range is
-            # down — the queue must not head-of-line-block other ranges).
-            take: list[int] = []
-            for i in list(queue):
-                if len(take) >= cfg.commit_ops:
-                    break
-                queue.remove(i)
-                if self._doomed(i, kinds, keys_a, his_a):
-                    shed_parked(i, t_commit)
-                elif self._admissible(i, t_commit, kinds, keys_a, his_a):
-                    take.append(i)
-                else:
-                    park(i, t_commit)
-            if not take:
-                t_free = max(t_commit, self._next_event_time(
-                    t_commit, parked, t_arr, n) or t_commit)
-                if t_free == t_commit:
-                    t_free = t_commit + cfg.linger_s  # no event: idle-spin guard
-                continue
+    def commit(self, batch: OpBatch, arrive: np.ndarray, t_commit: float,
+               qdepth: int) -> tuple:
+        """One leg per group touched: quorum commit and primary apply
+        (scaled by the group's latency spike), then the leg's maintenance.
+        The commit is done when its slowest leg is; maintenance is folded
+        into each leg's service, so the loop is charged none separately."""
+        cfg = self.config
+        point = self.partitioner.shard_of(batch.keys)
+        legs: dict[int, list[int]] = {}
+        for pos in range(len(batch)):
+            for gid in self._route(batch.kinds[pos], batch.keys[pos],
+                                   batch.his[pos], point[pos]):
+                legs.setdefault(gid, []).append(pos)
+        done = np.full(len(batch), t_commit)
+        leg_totals, debt_max = [], 0
+        for gid, members in legs.items():
+            g = self.groups[gid]
+            sub = rows_at(batch, members)
+            rows = write_rows(sub)
+            wal_s = 0.0
+            if len(rows[0]):
+                lsn, wal_s = g.commit(*rows)
+                self.acked.append((gid, lsn, *rows))
+            res = g.apply_primary(sub)
+            spike = g.spike(t_commit)
+            op_service = np.asarray(res.latency_s, np.float64)
+            leg_done = t_commit + spike * (wal_s + np.cumsum(op_service))
+            done[members] = np.maximum(done[members], leg_done)
+            io0 = g.primary.engine.io_time_s()
+            debt = g.primary.engine.maintain(cfg.maintain_budget)
+            maintain_s = g.primary.engine.io_time_s() - io0
+            leg_totals.append(spike * (wal_s + float(op_service.sum()))
+                              + maintain_s)
+            debt_max = max(debt_max, int(debt))
+            self._gwm[gid].record(t_commit, done[members] - arrive[members],
+                                  ops=len(members), queue_depth=qdepth,
+                                  debt=int(debt))
+        service_s = max(leg_totals)
+        if self.tracer is not None:
+            self.tracer.complete("commit", "group_commit", t_commit,
+                                 service_s, ops=len(batch), legs=len(legs))
+        return done, service_s, 0.0, debt_max
 
-            idx = np.asarray(take, np.int64)
-            legs: dict[int, list[int]] = {}
-            for pos, i in enumerate(take):
-                for gid in self._gids_of(i, kinds, keys_a, his_a):
-                    legs.setdefault(gid, []).append(pos)
-            done = np.full(len(take), t_commit)
-            leg_totals, debt_max = [], 0
-            for gid, members in legs.items():
-                g = self.groups[gid]
-                sub_idx = idx[members]
-                sub = OpBatch(kinds[sub_idx], keys_a[sub_idx],
-                              vals_a[sub_idx], his_a[sub_idx])
-                wmask = np.isin(np.asarray(sub.kinds), _WRITE_KINDS)
-                wal_s = 0.0
-                if wmask.any():
-                    lsn, wal_s = g.commit(sub.kinds[wmask],
-                                          sub.keys[wmask], sub.vals[wmask])
-                    self.acked.append((gid, lsn, sub.kinds[wmask].copy(),
-                                       sub.keys[wmask].copy(),
-                                       sub.vals[wmask].copy()))
-                res = g.apply_primary(sub)
-                spike = g.spike(t_commit)
-                op_service = np.asarray(res.latency_s, np.float64)
-                leg_done = t_commit + spike * (wal_s + np.cumsum(op_service))
-                for pos, d in zip(members, leg_done):
-                    done[pos] = max(done[pos], d)
-                io0 = g.primary.engine.io_time_s()
-                debt = g.primary.engine.maintain(cfg.maintain_budget)
-                maintain_s = g.primary.engine.io_time_s() - io0
-                leg_totals.append(spike * (wal_s + float(op_service.sum()))
-                                  + maintain_s)
-                debt_max = max(debt_max, int(debt))
-                gwm[gid].record(t_commit, done[members] - t_arr[sub_idx],
-                                ops=len(members), queue_depth=len(queue),
-                                debt=int(debt))
-            service_s = max(leg_totals)
-            e2e = done - t_arr[idx]
-            tracker.record_commit(
-                t_commit=t_commit,
-                kinds=[_KIND_NAMES[int(k)] for k in kinds[idx]],
-                e2e_s=e2e, queue_delay_s=t_commit - t_arr[idx],
-                qdepth_after=len(queue), service_s=service_s,
-                maintain_s=0.0, debt=debt_max)
-            if self.obs is not None:
-                self.tracer.complete("commit", "group_commit", t_commit,
-                                     service_s, ops=len(idx),
-                                     legs=len(legs))
-                wm.record(t_commit, e2e, ops=len(idx),
-                          queue_depth=len(queue), debt=debt_max)
-            t_free = t_commit + service_s
-
-        t_end = t_free
+    def finish(self, t_end: float, drain: bool) -> dict:
+        """Bring the failure machinery to ``t_end``, then drain every live
+        primary."""
         self._tick(t_end)
         if drain:
             for g in self.groups:
                 if g.primary is not None and g.primary.alive:
                     g.primary.engine.drain()
+        return {}
 
-        offered = {name: int((kinds == k).sum())
-                   for k, name in _KIND_NAMES.items()}
-        report = tracker.report(offered=offered, t_end=t_end)
-        report["service_model"] = "charged"
-        report["config"] = dataclasses.asdict(cfg)
+    # ----------------------------------------------------------------- serve
+    def run(self, trace: ArrivalTrace, *, drain: bool = True) -> dict:
+        self._bootstrap(trace)
+        self._gwm = [WindowedMetrics(self.window_s) for _ in self.groups]
+        self._ops = trace.ops       # one trace: queue items are its indices
+        self._point_gid = self.partitioner.shard_of(trace.ops.keys)
+        queue = SharedFifo(self.config.max_queue)
+        queue.add_tenant(0)
+        report, t_end = serve({0: trace}, queue, self, drain=drain)
         failovers = [ev for g in self.groups for ev in g.failovers]
         report["replication"] = {
-            "config": dataclasses.asdict(rep),
+            "config": dataclasses.asdict(self.rep),
             "n_groups": len(self.groups),
             "acked_commits": len(self.acked),
             "acked_rows": int(sum(g.acked_rows for g in self.groups)),
@@ -410,21 +340,11 @@ class ReplicatedFrontend:
             "groups": [g.describe() for g in self.groups],
             "availability": [
                 {"gid": g.gid, "downtime_s": float(g.downtime_s),
-                 "timeline": gwm[g.gid].finish(t_end)}
+                 "timeline": self._gwm[g.gid].finish(t_end)}
                 for g in self.groups],
         }
         if self.chaos is not None:
             report["replication"]["chaos"] = self.chaos.describe()
-        if self.obs is not None:
-            block = wm.finish(t_end)
-            block["trace"] = {"events": len(self.tracer),
-                              "dropped_events": self.tracer.dropped_events,
-                              "categories": sorted(
-                                  self.tracer.categories())}
-            if self.obs.trace_path:
-                self.tracer.save(self.obs.trace_path)
-                block["trace"]["path"] = self.obs.trace_path
-            report["obs"] = block
         return report
 
 

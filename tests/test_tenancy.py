@@ -17,7 +17,7 @@ import pytest
 
 from repro.core.engine_api import OpBatch, OpKind, make_engine
 from repro.ingest import (DurabilityConfig, FrontendConfig, PoissonArrivals,
-                          make_trace, multiplex)
+                          make_trace, multiplex, run_open_loop)
 from repro.tenancy import (MultiTenantFrontend, NamespaceMap, SnapshotManager,
                            TenantConfig, WeightedFairQueue, recover_namespace,
                            run_multi_tenant)
@@ -290,6 +290,30 @@ def test_unfair_mode_sheds_victims_too():
     assert adm["2"]["shed"] > 0, "aggressor bursts overflow the shared FIFO"
     assert adm["0"]["shed"] + adm["1"]["shed"] > 0, \
         "shared FIFO lets the aggressor shed victims (the unfair baseline)"
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("nbtree", dict(f=3, sigma=128)),
+    ("lsm", dict(mem_pairs=256)),
+])
+def test_one_tenant_fifo_serves_like_single_engine_frontend(name, kw):
+    """The single-engine frontend is the one-tenant case of the shared
+    FIFO: tenant 0's namespace encoding is the identity, so serving one
+    seeded trace through either frontend gives the same aggregate report."""
+    wl = make_workload("delete-churn", n_ops=600, preload=256,
+                       key_space=1 << 14, seed=5)
+    trace = make_trace(wl, PoissonArrivals(60_000.0), arrival_seed=9)
+    cfg = FrontendConfig(max_queue=48, commit_ops=16, linger_s=2e-4)
+    solo = run_open_loop(make_engine(name, **kw), trace,
+                         config=cfg)["open_loop"]
+    one = run_multi_tenant(make_engine(name, **kw), [TenantConfig(0)],
+                           {0: trace}, config=cfg, fair=False)["open_loop"]
+    assert solo["n_shed"] > 0, "the trace must overload the queue"
+    assert one["per_kind_e2e"] == solo["per_kind_e2e"]
+    assert one["n_shed"] == solo["n_shed"]
+    assert one["shed_per_kind"] == solo["shed_per_kind"]
+    assert one["server"]["n_commits"] == solo["server"]["n_commits"]
+    assert one["pending_debt_at_end"] == solo["pending_debt_at_end"]
 
 
 def test_slo_targets_in_report():
